@@ -10,13 +10,12 @@ dedicated workers:
               with ``reuse=True``); runs on the dispatch worker in
               submission order, so the device queue order is the frame
               submission order.
-  finalize  — materialize the device result, decode to pb.Response rows,
-              resolve ineligible rows with one batched service call,
-              release the staging lease; runs on the finalize worker,
-              FIFO.
-  serialize — response frames serialize on the shared chunked serializer
-              pool (transport_grpc.serialize_batch_response), so frame
-              i-1's serialization overlaps frame i's device execution.
+  finalize  — materialize the device result and assemble the reply as
+              the unary handler does (transport_grpc.native_batch_reply:
+              frame-table codes, one batched service call for the rows
+              the kernel did not serve, the staging lease released, the
+              frames joined); runs on the finalize worker, FIFO, so
+              frame i-1's assembly overlaps frame i's device execution.
 
 A BoundedSemaphore of ``depth`` slots is the backpressure: submit blocks
 the feeding stream's thread while ``depth`` frames are between dispatch
@@ -41,7 +40,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Optional
 
-from .tracing import STAGE_DECODE, STAGE_SERIALIZE, untimed
+from .tracing import untimed
 
 
 class DevicePipeline:
@@ -110,11 +109,7 @@ class DevicePipeline:
 
     def _finalize(self, raw, messages, finalize, deadline, span,
                   out: Future, t0: float) -> None:
-        from .transport_grpc import (
-            decode_native_rows,
-            resolve_fallback_rows,
-            serialize_batch_response,
-        )
+        from .transport_grpc import native_batch_reply
 
         worker = self.worker
         try:
@@ -134,27 +129,12 @@ class DevicePipeline:
             if result is None:
                 payload = self._pb_fallback(raw, deadline, span)
             else:
-                batch = result[0]
                 obs = getattr(worker, "obs", None)
                 tracer = obs.tracer if obs is not None else None
-                stage = tracer.stage if tracer is not None else untimed
-                with stage(STAGE_DECODE, span):
-                    responses, fb_rows, fb_reqs = decode_native_rows(
-                        messages, result
-                    )
-                resolve_fallback_rows(worker, responses, fb_rows, fb_reqs,
-                                      deadline, span=span)
-                # staging lease: every pooled buffer (row arrays, masks,
-                # regex matrices, owner bits) recycles only AFTER the
-                # response rows are fully assembled
-                batch.release_staging()
-                telemetry = getattr(worker, "telemetry", None)
-                if telemetry is not None:
-                    telemetry.batch_latency.observe(
-                        time.perf_counter() - t0
-                    )
-                with stage(STAGE_SERIALIZE, span):
-                    payload = serialize_batch_response(responses)
+                payload, _ = native_batch_reply(
+                    worker, messages, result, deadline, t0, span=span,
+                    stage=tracer.stage if tracer is not None else untimed,
+                )
             if not out.done():
                 out.set_result(payload)
         except BaseException as err:  # noqa: BLE001

@@ -11,12 +11,14 @@ the batched TPU evaluation path directly.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from concurrent import futures
 from typing import Optional
 
 import grpc
+import numpy as np
 
 from ..models.model import (
     Attribute,
@@ -325,14 +327,13 @@ _MESSAGE_SIZE_OPTIONS = (
     ("grpc.max_send_message_length", 64 * 1024 * 1024),
 )
 
-# batch-reply serialization runs on this shared pool, NOT the gRPC
-# dispatch thread: an 8192-row BatchResponse costs ~8 ms of protobuf
-# SerializeToString, which previously serialized the whole envelope on the
-# handler thread after the kernel was already done (part of the
-# wire-to-wire gap vs kernel-only throughput).  Chunks serialize
+# the pb fallback and service paths serialize their batch replies on this
+# shared pool, NOT the gRPC dispatch thread: an 8192-row BatchResponse
+# costs ~8 ms of protobuf SerializeToString.  Chunks serialize
 # concurrently and the dispatch thread only joins the length-delimited
 # frames — BatchResponse is `repeated Response responses = 1`, so the
-# frame concatenation IS the envelope encoding.
+# frame concatenation IS the envelope encoding.  Rows served natively take
+# their frames from the frame table (frame_table) and need no pool.
 _SER_POOL = futures.ThreadPoolExecutor(
     max_workers=4, thread_name_prefix="acs-pb-ser"
 )
@@ -351,19 +352,25 @@ def _varint(n: int) -> bytes:
             return bytes(out)
 
 
+def _frame(resp: pb.Response) -> bytes:
+    """One row's BatchResponse field-1 frame: key, length, message."""
+    body = resp.SerializeToString()
+    return b"\x0a" + _varint(len(body)) + body
+
+
 def _response_frames(chunk: list) -> bytes:
-    parts = []
-    for resp in chunk:
-        body = resp.SerializeToString()
-        parts.append(b"\x0a" + _varint(len(body)) + body)
-    return b"".join(parts)
+    return b"".join([_frame(resp) for resp in chunk])
 
 
 def serialize_batch_response(responses: list) -> bytes:
-    """BatchResponse wire bytes from per-row pb.Response messages; chunked
-    across the serializer pool for large batches (identical bytes to
+    """BatchResponse wire bytes from per-row pb.Response messages, chunked
+    across the serializer pool for large batches, or from the rows' frames
+    already serialized (bytes, as native_batch_reply hands them in), joined
+    as they are.  Identical bytes to
     ``pb.BatchResponse(responses=...).SerializeToString()`` — asserted by
-    tests/test_grpc_transport.py)."""
+    tests/test_grpc_transport.py."""
+    if responses and isinstance(responses[0], bytes):
+        return b"".join(responses)
     if len(responses) <= _SER_CHUNK:
         return _response_frames(responses)
     chunks = [
@@ -373,52 +380,116 @@ def serialize_batch_response(responses: list) -> bytes:
     return b"".join(_SER_POOL.map(_response_frames, chunks))
 
 
-def decode_native_rows(messages: list[bytes], out) -> tuple:
-    """Per-row pb.Response assembly from a native wire result
-    ``(batch, decision, cacheable, status)``.  Ineligible / non-200 rows
-    are parsed back to Request models and returned for ONE batched
-    fallback call (resolve_fallback_rows) instead of per-row service
-    round-trips.  Shared by the unary IsAllowedBatch handler and the
-    streaming pipeline (srv/pipeline.py)."""
+@functools.cache
+def frame_table() -> tuple:
+    """The frame of every reply a row the kernel served can get, indexed
+    ``decision code * 2 + cacheable``: status ``{200, "success"}``, one of
+    the DECISION_NAMES decisions, cacheable or not.  Built once, from the
+    protobuf serializer itself."""
+    return tuple(
+        _frame(pb.Response(
+            decision=DECISION_TO_PB[DECISION_NAMES[code]],
+            evaluation_cacheable=cacheable,
+            operation_status=pb.OperationStatus(code=200, message="success"),
+        ))
+        for code in range(len(DECISION_NAMES))
+        for cacheable in (False, True)
+    )
+
+
+class NativeRows:
+    """One native-wire batch as decode_native_rows reads it: ``codes``, the
+    frame_table index of each row the kernel served (eligible, status 200,
+    a decision in the table) and 0 for the other rows; ``counts``, the
+    served rows per table index; ``fallback``, row -> pb.Response for every
+    other row, which the parse-error reply and resolve_fallback_rows
+    fill."""
+
+    __slots__ = ("codes", "counts", "fallback", "fallback_rows",
+                 "fallback_reqs")
+
+    def __init__(self, codes, counts):
+        self.codes, self.counts = codes, counts
+        self.fallback: dict = {}
+        self.fallback_rows: list[int] = []
+        self.fallback_reqs: list = []
+
+    @property
+    def n_served(self) -> int:
+        return int(self.counts.sum())
+
+    def frames(self) -> list:
+        """Every row's frame, in row order."""
+        table = frame_table()
+        frames = [table[k] for k in self.codes.tolist()]
+        for b, resp in self.fallback.items():
+            frames[b] = _frame(resp)
+        return frames
+
+    def count(self, telemetry) -> None:
+        """Decision and path counters: the served rows per decision from
+        ``counts``, the fallback rows one by one."""
+        pairs = self.counts.reshape(-1, 2).sum(axis=1).tolist()
+        for code, n in enumerate(pairs):
+            if n:
+                telemetry.decisions.inc(DECISION_NAMES[code], n)
+        for resp in self.fallback.values():
+            telemetry.decisions.inc(PB_TO_DECISION.get(resp.decision, "DENY"))
+        n_served = sum(pairs)
+        if n_served:
+            telemetry.record_path("wire-frame-table", n_served)
+        if self.fallback:
+            telemetry.record_path("wire-frame-pb", len(self.fallback))
+
+    def shed(self) -> bool:
+        """Whole-batch shed: rows, none served, every one an admission
+        status (stamped so the router may retry the batch elsewhere)."""
+        return bool(self.fallback) and self.n_served == 0 and all(
+            r.operation_status.code in SHED_CODES
+            for r in self.fallback.values()
+        )
+
+
+def decode_native_rows(messages: list[bytes], out) -> NativeRows:
+    """Frame-table codes from a native wire result ``(batch, decision,
+    cacheable, status)``, in numpy: a served row becomes an index into
+    frame_table, with no per-row Python object.  Ineligible rows, non-200
+    rows and decisions outside the table are parsed back to Request models
+    for ONE batched fallback call (resolve_fallback_rows) instead of
+    per-row service round-trips; a row that does not parse gets a 500
+    reply.  The codes are a copy: the staging buffers may recycle once
+    this returns."""
     batch, decision, cacheable, status = out
-    responses: list = [None] * len(messages)
-    fallback_rows: list[int] = []
-    fallback_reqs: list = []
-    for b, message in enumerate(messages):
-        if not batch.eligible[b] or status[b] != 200:
-            try:
-                req = request_from_pb(pb.Request.FromString(message))
-            except Exception as err:
-                responses[b] = pb.Response(
-                    decision=pb.DENY,
-                    operation_status=pb.OperationStatus(
-                        code=500, message=str(err)
-                    ),
-                )
-                continue
-            fallback_rows.append(b)
-            fallback_reqs.append(req)
+    n = len(messages)
+    decision = np.asarray(decision[:n], dtype=np.int64)
+    served = (np.asarray(batch.eligible[:n], dtype=bool)
+              & (np.asarray(status[:n]) == 200)
+              & (decision >= 0) & (decision < len(DECISION_NAMES)))
+    codes = np.where(served, decision * 2 + (np.asarray(cacheable[:n]) > 0),
+                     0)
+    rows = NativeRows(codes, np.bincount(
+        codes[served], minlength=2 * len(DECISION_NAMES)))
+    for b in np.flatnonzero(~served).tolist():
+        try:
+            req = request_from_pb(pb.Request.FromString(messages[b]))
+        except Exception as err:
+            rows.fallback[b] = pb.Response(
+                decision=pb.DENY,
+                operation_status=pb.OperationStatus(
+                    code=500, message=str(err)
+                ),
+            )
             continue
-        cach = (
-            False if cacheable[b] < 0 else bool(cacheable[b])
-        )
-        responses[b] = pb.Response(
-            decision=DECISION_TO_PB[
-                DECISION_NAMES[int(decision[b])]
-            ],
-            evaluation_cacheable=cach,
-            operation_status=pb.OperationStatus(
-                code=200, message="success"
-            ),
-        )
-    return responses, fallback_rows, fallback_reqs
+        rows.fallback_rows.append(b)
+        rows.fallback_reqs.append(req)
+    return rows
 
 
-def resolve_fallback_rows(worker, responses: list, fallback_rows: list,
+def resolve_fallback_rows(worker, responses, fallback_rows: list,
                           fallback_reqs: list, deadline, span=None) -> None:
     """Resolve the rows decode_native_rows could not serve natively with
-    one batched service call (observe=False: the caller records
-    batch-level telemetry for ALL rows itself)."""
+    one batched service call into ``responses[row]`` (observe=False: the
+    caller records batch-level telemetry for ALL rows itself)."""
     if not fallback_reqs:
         return
     if span is not None:
@@ -432,6 +503,29 @@ def resolve_fallback_rows(worker, responses: list, fallback_rows: list,
         ),
     ):
         responses[b] = response_to_pb(resp)
+
+
+def native_batch_reply(worker, messages: list[bytes], out, deadline,
+                       t0: float, span=None, stage=untimed) -> tuple:
+    """The reply to a batch the native wire path evaluated, ``(payload,
+    shed)``: frame-table codes (``decode``), ONE batched fallback call for
+    the rows the kernel did not serve, the batch's telemetry, then the
+    frames joined (``serialize``).  Shared by the unary IsAllowedBatch
+    handler and the streaming pipeline (srv/pipeline.py)."""
+    with stage(STAGE_DECODE, span):
+        rows = decode_native_rows(messages, out)
+    resolve_fallback_rows(worker, rows.fallback, rows.fallback_rows,
+                          rows.fallback_reqs, deadline, span=span)
+    # staging lease: every pooled buffer (row arrays, masks, regex
+    # matrices, owner bits) recycles only AFTER the rows are assembled
+    out[0].release_staging()
+    telemetry = getattr(worker, "telemetry", None)
+    if telemetry is not None:
+        telemetry.batch_latency.observe(time.perf_counter() - t0)
+        rows.count(telemetry)
+    with stage(STAGE_SERIALIZE, span):
+        payload = serialize_batch_response(rows.frames())
+    return payload, rows.shed()
 
 
 POLICY_EPOCH_METADATA_KEY = "x-acs-policy-epoch"
@@ -609,30 +703,15 @@ class GrpcServer:
                 except Exception:
                     out = None
                 if out is not None:
-                    # per-row assembly + ONE batched fallback call for
-                    # ineligible rows (per-row service.is_allowed would
-                    # wait out a micro-batch window each); observe=False
-                    # on the fallback: this handler records batch_latency
-                    # and decision counts for ALL rows below
-                    with stage(STAGE_DECODE, span):
-                        responses, fallback_rows, fallback_reqs = \
-                            decode_native_rows(messages, out)
-                    resolve_fallback_rows(
-                        worker, responses, fallback_rows, fallback_reqs,
-                        deadline, span=span,
+                    # ONE batched fallback call for the rows the kernel
+                    # did not serve (per-row service.is_allowed would wait
+                    # out a micro-batch window each); observe=False on
+                    # it: native_batch_reply records batch_latency and
+                    # decision counts for ALL rows
+                    return native_batch_reply(
+                        worker, messages, out, deadline, t0, span=span,
+                        stage=stage,
                     )
-                    telemetry = getattr(worker, "telemetry", None)
-                    if telemetry is not None:
-                        telemetry.batch_latency.observe(
-                            time.perf_counter() - t0
-                        )
-                        for resp in responses:
-                            telemetry.decisions.inc(
-                                PB_TO_DECISION.get(resp.decision, "DENY")
-                            )
-                    with stage(STAGE_SERIALIZE, span):
-                        payload = serialize_batch_response(responses)
-                    return payload, _shed_all(responses)
             with stage(STAGE_TRANSPORT_PARSE, span):
                 request = pb.BatchRequest.FromString(raw)
                 reqs = [request_from_pb(r) for r in request.requests]

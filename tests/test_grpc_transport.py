@@ -58,26 +58,163 @@ def wire_request(role="superadministrator-r-id", action=None):
     return msg
 
 
-def test_serialize_batch_response_byte_identity():
-    """The off-dispatch-thread batch serializer must produce byte-identical
-    envelopes to protobuf's own BatchResponse serialization, across the
-    chunking threshold (length-delimited field-1 frames ARE the
-    envelope)."""
+class _Batch:
+    """A native wire result's batch: eligibility and the staging lease."""
+
+    def __init__(self, eligible):
+        self.eligible = eligible
+        self.released = 0
+
+    def release_staging(self):
+        self.released += 1
+
+
+class _Service:
+    """Answers every fallback row with ``code``."""
+
+    def __init__(self, code):
+        self.code = code
+
+    def is_allowed_batch(self, reqs, observe=True, deadline=None):
+        from access_control_srv_tpu.models.model import (
+            Decision,
+            OperationStatus,
+            Response,
+        )
+
+        return [Response(decision=Decision.DENY, evaluation_cacheable=True,
+                         operation_status=OperationStatus(self.code, "fb"))
+                for _ in reqs]
+
+
+class _Worker:
+    def __init__(self, code):
+        from access_control_srv_tpu.srv.telemetry import Telemetry
+
+        self.service = _Service(code)
+        self.telemetry = Telemetry()
+
+
+# rows the kernel did not serve, as the native wire result shows them
+FALLBACK_KINDS = ("ineligible", "non-200", "out-of-table", "unparsable")
+
+
+def native_result(n, layout):
+    """``(messages, (batch, decision, cacheable, status))`` of ``n`` rows:
+    served rows cycle through every (decision 0..2, cacheable -1/0/1);
+    ``mixed`` puts each fallback kind at the first, middle and last rows,
+    ``none-served`` makes every row a fallback row that parses."""
+    import numpy as np
+
+    decision = np.array([i % 3 for i in range(n)], np.int32)
+    cacheable = np.array([i // 3 % 3 - 1 for i in range(n)], np.int32)
+    status = np.full(n, 200, np.int32)
+    eligible = np.ones(n, bool)
+    messages = [wire_request().SerializeToString()] * n
+    kinds = {}
+    if layout == "mixed":
+        for start in (0, n // 2, n - len(FALLBACK_KINDS)):
+            for j in range(len(FALLBACK_KINDS)):
+                if 0 <= start + j < n:
+                    kinds[start + j] = FALLBACK_KINDS[(start + j) % 4]
+    elif layout == "none-served":
+        kinds = {b: FALLBACK_KINDS[b % 3] for b in range(n)}
+    for b, kind in kinds.items():
+        if kind == "ineligible":
+            eligible[b] = False
+        elif kind == "non-200":
+            status[b] = 403
+        elif kind == "out-of-table":
+            decision[b] = 3 + b % 2
+        else:
+            eligible[b] = False
+            messages[b] = b"\xff\xff"
+    return messages, (_Batch(eligible), decision, cacheable, status)
+
+
+def per_row_reply(worker, messages, out):
+    """The per-row assembly the frame table replaces: a pb.Response and a
+    decision count for every row."""
+    from collections import Counter
+
+    from access_control_srv_tpu.ops.compile import DECISION_NAMES
     from access_control_srv_tpu.srv.transport_grpc import (
-        _SER_CHUNK,
+        DECISION_TO_PB,
+        PB_TO_DECISION,
+        SHED_CODES,
+        request_from_pb,
+        resolve_fallback_rows,
+    )
+
+    batch, decision, cacheable, status = out
+    responses = [None] * len(messages)
+    fallback_rows, fallback_reqs = [], []
+    for b, message in enumerate(messages):
+        if (not batch.eligible[b] or status[b] != 200
+                or int(decision[b]) not in DECISION_NAMES):
+            try:
+                req = request_from_pb(pb.Request.FromString(message))
+            except Exception as err:
+                responses[b] = pb.Response(
+                    decision=pb.DENY,
+                    operation_status=pb.OperationStatus(
+                        code=500, message=str(err)),
+                )
+                continue
+            fallback_rows.append(b)
+            fallback_reqs.append(req)
+            continue
+        responses[b] = pb.Response(
+            decision=DECISION_TO_PB[DECISION_NAMES[int(decision[b])]],
+            evaluation_cacheable=False if cacheable[b] < 0
+            else bool(cacheable[b]),
+            operation_status=pb.OperationStatus(code=200, message="success"),
+        )
+    resolve_fallback_rows(worker, responses, fallback_rows, fallback_reqs,
+                          None)
+    counts = Counter(PB_TO_DECISION.get(r.decision, "DENY")
+                     for r in responses)
+    shed = bool(responses) and all(
+        r.operation_status.code in SHED_CODES for r in responses)
+    return responses, fallback_rows, dict(counts), shed
+
+
+BYTE_IDENTITY = (
+    [("pb", n, None) for n in (0, 1, 3, 512, 513, 2 * 512 + 17)]
+    + [("table", n, layout) for n in (0, 1, 511, 512, 513, 4096)
+       for layout in ("served", "mixed", "none-served", "shed")]
+)
+
+
+@pytest.mark.parametrize(
+    "path,n,layout", BYTE_IDENTITY,
+    ids=["-".join(str(p) for p in case if p is not None)
+         for case in BYTE_IDENTITY])
+def test_serialize_batch_response_byte_identity(path, n, layout):
+    """Batch replies must be byte-identical to protobuf's own
+    BatchResponse serialization of the per-row responses: the pb path's
+    off-dispatch-thread serializer across the chunking threshold
+    (length-delimited field-1 frames ARE the envelope), and the native
+    path's frame table against per-row assembly — frames, payload,
+    fallback rows, decision counts and the shed stamp."""
+    from access_control_srv_tpu.srv.transport_grpc import (
+        _frame,
+        decode_native_rows,
+        native_batch_reply,
+        resolve_fallback_rows,
         serialize_batch_response,
     )
 
-    def row(i):
-        return pb.Response(
-            decision=[pb.PERMIT, pb.DENY, pb.INDETERMINATE][i % 3],
-            evaluation_cacheable=bool(i % 2),
-            operation_status=pb.OperationStatus(
-                code=200 if i % 5 else 403, message=f"m{i}" * (i % 7)
-            ),
-        )
+    if path == "pb":
+        def row(i):
+            return pb.Response(
+                decision=[pb.PERMIT, pb.DENY, pb.INDETERMINATE][i % 3],
+                evaluation_cacheable=bool(i % 2),
+                operation_status=pb.OperationStatus(
+                    code=200 if i % 5 else 403, message=f"m{i}" * (i % 7)
+                ),
+            )
 
-    for n in (0, 1, 3, _SER_CHUNK, _SER_CHUNK + 1, 2 * _SER_CHUNK + 17):
         responses = [row(i) for i in range(n)]
         expected = pb.BatchResponse(responses=responses).SerializeToString()
         assert serialize_batch_response(responses) == expected, n
@@ -86,6 +223,89 @@ def test_serialize_batch_response_byte_identity():
             serialize_batch_response(responses)
         )
         assert list(parsed.responses) == responses
+        return
+
+    code = 429 if layout == "shed" else 200
+    messages, out = native_result(
+        n, "none-served" if layout == "shed" else layout)
+    responses, fallback_rows, counts, shed = per_row_reply(
+        _Worker(code), messages, out)
+    # the fallback service answers "fb", an unparsable row its error
+    n_table = sum(r.operation_status.message == "success" for r in responses)
+
+    rows = decode_native_rows(messages, out)
+    resolve_fallback_rows(_Worker(code), rows.fallback, rows.fallback_rows,
+                          rows.fallback_reqs, None)
+    assert rows.fallback_rows == fallback_rows
+    assert rows.n_served == n_table
+    assert rows.frames() == [_frame(r) for r in responses]
+
+    worker = _Worker(code)
+    payload, shed_native = native_batch_reply(worker, messages, out, None,
+                                              0.0)
+    assert payload == pb.BatchResponse(
+        responses=responses).SerializeToString()
+    assert shed_native == shed == (layout == "shed" and n > 0)
+    assert out[0].released == 1
+    snap = worker.telemetry.snapshot()
+    assert snap["decisions"] == counts
+    assert snap["paths"].get("wire-frame-table", 0) == n_table
+    assert snap["paths"].get("wire-frame-pb", 0) == n - n_table
+
+
+def _raw_batch(messages):
+    """A BatchRequest envelope from per-row message bytes, as sent."""
+    from access_control_srv_tpu.srv.transport_grpc import _varint
+
+    return b"".join(b"\x0a" + _varint(len(m)) + m for m in messages)
+
+
+def test_stream_frames_match_unary_on_mixed_batch(rig):
+    """IsAllowedStream and IsAllowedBatch assemble a mixed native batch
+    (rows the kernel serves, rows it leaves to the service, a row that
+    does not parse) into the same frames, and both count each row once
+    under wire-frame-table or wire-frame-pb."""
+    worker, client = rig
+    assert worker.evaluator.native_active
+    token_row = wire_request()
+    token_row.context.subject.value = json.dumps(
+        {"token": "unknown-token"}).encode()
+    messages = []
+    for i in range(24):
+        msg = token_row if i % 5 == 4 else wire_request(
+            role="superadministrator-r-id" if i % 2 else "nobody")
+        messages.append(msg.SerializeToString())
+    messages[11] = b"\xff\xff"
+    raw = _raw_batch(messages)
+
+    def paths():
+        return worker.telemetry.paths.snapshot()
+
+    before = paths()
+    unary = client.channel.unary_unary(
+        "/acstpu.AccessControlService/IsAllowedBatch")(raw, timeout=60)
+    after_unary = paths()
+    streamed = list(client.channel.stream_stream(
+        "/acstpu.AccessControlService/IsAllowedStream")(
+            iter([raw, raw]), timeout=60))
+    after_stream = paths()
+    assert streamed == [unary, unary]
+
+    replies = pb.BatchResponse.FromString(unary).responses
+    assert len(replies) == 24
+    assert replies[11].operation_status.code == 500
+    # the token rows (4, 9, 14, 19) and the unparsable row
+    n_pb = 5
+    n_table = 24 - n_pb
+
+    def delta(after, base, key):
+        return after.get(key, 0) - base.get(key, 0)
+
+    assert delta(after_unary, before, "native-wire") == n_table
+    assert delta(after_unary, before, "wire-frame-table") == n_table
+    assert delta(after_unary, before, "wire-frame-pb") == n_pb
+    assert delta(after_stream, after_unary, "wire-frame-table") == 2 * n_table
+    assert delta(after_stream, after_unary, "wire-frame-pb") == 2 * n_pb
 
 
 def test_is_allowed_over_wire(rig):
