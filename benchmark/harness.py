@@ -1,17 +1,36 @@
 """One cell of the benchmark, once: set-up, the measured window, the
 comparison with the plain reference, and the result line.
 
-Everything a cell is made of is found by name: the cell in
-``BENCHMARK.json``, its configuration's file (``configs/<name>.json``), its
-traffic mix (``traffic/<name>.json``) and one reader per metric
-(``metrics/<name>.py``).  From the program this takes only the system under
-test (``Worker`` behind ``GrpcServer``, driven through ``GrpcClient``'s
-channel), its stage histograms, path counters and compile state.
+Everything a cell is made of is found by name, so a later cell, mix,
+metric or configuration is added as new files and ``BENCHMARK.json``
+entries alone:
+
+- the cell: an entry of ``BENCHMARK.json``'s ``workloads``;
+- a traffic mix: ``traffic/<name>.json`` (``traffic.py``), whose
+  ``rows.kind`` names a row kind;
+- a row kind: ``row_kinds/<kind>.py`` with ``rows(n, g, params) -> list``
+  of row dicts (``traffic.row``'s shape), drawn from the generator ``g``;
+- a metric: ``metrics/<name>.py`` with ``read(reading) -> float | None``;
+- a configuration: its file (``BENCHMARK.json``'s ``file``) with
+  ``tree``: ``{"seed": "<dir>"}`` or ``{"builder": "<name>", "params":
+  {...}}``, a builder being ``tree_builders/<name>.py`` with
+  ``build(params, g) -> list`` of nested policy sets (``trees.py``);
+  ``reference``: ``<name>``, the plain reference
+  ``references/<name>.py`` (``reference.py`` when absent), with
+  ``Reference(tree).decide(Row(target, context))``, ``CODES``,
+  ``CONTROLS`` and ``Unsupported`` as ``reference.py`` has them;
+  ``control``: a key of that module's ``CONTROLS``; ``overlay``: the
+  program's configuration keys it sets; and ``small``: the overlay of
+  the configuration at the size the benchmark's tests run (a mix's
+  ``small`` likewise).
+
+From the program this takes only the system under test (``Worker`` behind
+``GrpcServer``, driven through ``GrpcClient``'s channel), its stage
+histograms, path counters and compile state.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import sys
@@ -27,6 +46,7 @@ for _p in (HERE, CHECKOUT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+import parts  # noqa: E402
 import reference  # noqa: E402
 import traffic  # noqa: E402
 import trees  # noqa: E402
@@ -34,6 +54,8 @@ import trees  # noqa: E402
 KERNEL_WAIT_S = 900.0
 LATE_ANSWER_S = 60.0      # how long past the window an answer is awaited
 TRACE_DIR = os.path.join(CHECKOUT, ".bench_trace")
+TREE_DIR = os.path.join(CHECKOUT, ".bench_scratch", "trees")
+ROWS_STREAM, TREE_STREAM = 1, 2        # traffic.rng streams of the seed
 PB_CODE = {1: 0, 2: 1, 3: 2}   # pb.Decision value -> reference.CODES
 TRACE_OBSERVABILITY = {"enabled": True,
                        "tracing": {"enabled": True, "sample_rate": 0.0}}
@@ -100,12 +122,15 @@ def _overlay(target: dict, extra: dict) -> None:
 
 def metric_reader(name: str):
     """``metrics/<name>.py``'s ``read(reading)``."""
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return parts.load("metrics", name).read
+
+
+def reference_module(config: dict):
+    """The configuration's plain reference: ``references/<name>.py``
+    where it names one, else ``reference.py``."""
+    if "reference" in config:
+        return parts.load("references", config["reference"])
+    return reference
 
 
 # ---------------------------------------------------------------- device
@@ -252,12 +277,10 @@ def build_workload(cell: Cell, seed: int) -> Workload:
     mix = cell.mix
     if mix["loop"] != "closed":
         raise Refused(f"the harness drives closed loops only: {mix['loop']}")
-    tree = trees.seed_doc(cell.config["tree"]["seed"])
+    tree = trees.build(cell.config["tree"], traffic.rng(seed, TREE_STREAM))
     n_rules = sum(len(p.get("rules") or []) for ps in tree
                   for p in ps["policies"])
-    g = traffic.rng(seed, 1)
-    pool = [traffic.make_rows(mix, mix["batch"], g)
-            for _ in range(mix["pool"])]
+    pool = traffic.make_pool(mix, traffic.rng(seed, ROWS_STREAM))
     return Workload(tree, n_rules, pool,
                     [traffic.batch_bytes(rows) for rows in pool])
 
@@ -343,10 +366,11 @@ def closed_loop(serving: Serving, wl: Workload, mix: dict, seconds: float,
 
 # ------------------------------------------------------------ comparison
 
-def expected_codes(ref: reference.Reference, rows: list) -> np.ndarray:
-    """The reference's decision code of every row."""
-    return np.array([reference.CODES[ref.decide(
-        reference.Row(r["target"], r["context"]))] for r in rows], np.int8)
+def expected_codes(module, ref, rows: list) -> np.ndarray:
+    """The decision code of every row by ``ref``, a ``Reference`` (or a
+    control) of the reference ``module``."""
+    return np.array([module.CODES[ref.decide(
+        module.Row(r["target"], r["context"]))] for r in rows], np.int8)
 
 
 def compare_calls(calls: list, expect: list) -> dict:
@@ -427,10 +451,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
 
     t = time.perf_counter()
     wl = build_workload(cell, seed)
+    # a tree or row the reference cannot judge fails here, before set-up
+    refs = reference_module(cell.config)
+    ref = refs.Reference(wl.tree)
+    overlay = dict(cell.config.get("overlay") or {})
+    overlay["seed_data"] = trees.seed_data(
+        cell.config["tree"], wl.tree, os.path.join(TREE_DIR, cell.name))
     say("harness prep", seconds=time.perf_counter() - t, rules=wl.n_rules)
 
-    overlay = dict(cell.config.get("overlay") or {})
-    overlay["seed_data"] = trees.seed_paths(cell.config["tree"]["seed"])
     if trace:
         overlay["observability"] = TRACE_OBSERVABILITY
 
@@ -476,8 +504,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
 
     # ---------------------------- the reference, once the window closed
     t = time.perf_counter()
-    ref = reference.Reference(wl.tree)
-    result = compare_calls(calls, [expected_codes(ref, rows)
+    result = compare_calls(calls, [expected_codes(refs, ref, rows)
                                    for rows in wl.pool])
     say("reference", seconds=time.perf_counter() - t, **result)
     errors = [c.error for c in calls if c.error]

@@ -13,6 +13,7 @@ for path in (BENCH, REPO):
     if path not in sys.path:
         sys.path.insert(0, path)
 
+import harness  # noqa: E402
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     SPEC = json.load(_f)
@@ -28,8 +29,13 @@ def make_checkout(tmp_path, spec: dict = SPEC):
     return tmp_path
 
 
-# every cell at a size a test run holds: 256-row batches
-SMALL = {
-    "seed.batch4096": {"mix": {"batch": 256}},
-}
+def small(cell: str) -> dict:
+    """The cell at a size a test run holds: the ``small`` overlays of its
+    configuration file and its mix file."""
+    found = harness.load_cell(cell, SPEC)
+    return {"config": found.config.get("small", {}),
+            "mix": found.mix.get("small", {})}
+
+
+SMALL = {w["name"]: small(w["name"]) for w in SPEC["workloads"]}
 BIG_SEED = 2**31 + 12345

@@ -19,16 +19,22 @@ def _flip(decision):
 
 def answer_altered(monkeypatch):
     """A decision altered where it is produced (the native wire path's
-    first row of each batch, and every answer of the other paths)."""
+    first served row of each batch, and every answer of the other
+    paths)."""
     from access_control_srv_tpu.srv import transport_grpc as tg
 
     decode, to_pb = tg.decode_native_rows, tg.response_to_pb
 
     def bad_decode(messages, out):
-        responses, rows, reqs = decode(messages, out)
-        if responses and responses[0] is not None:
-            responses[0].decision = _flip(responses[0].decision)
-        return responses, rows, reqs
+        rows = decode(messages, out)
+        unserved = set(rows.fallback) | set(rows.fallback_rows)
+        served = [b for b in range(len(rows.codes)) if b not in unserved]
+        if served:
+            # a frame-table code is decision * 2 + cacheable: the next
+            # decision, the same cacheable bit
+            n_codes = len(tg.frame_table())
+            rows.codes[served[0]] = (rows.codes[served[0]] + 2) % n_codes
+        return rows
 
     def bad_to_pb(response):
         msg = to_pb(response)

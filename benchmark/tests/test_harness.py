@@ -17,6 +17,7 @@ from conftest import BIG_SEED, SMALL, SPEC, make_checkout
 import harness
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
+ALG = "urn:oasis:names:tc:xacml:3.0:rule-combining-algorithm:permit-overrides"
 
 
 @pytest.mark.parametrize("cell", sorted(SMALL))
@@ -52,8 +53,8 @@ def _cli(cwd, env=None):
         env={**os.environ, "JAX_PLATFORMS": "cpu", **(env or {})})
 
 
-# seed.batch4096 at full size on the CPU
-ARGS = ["--workload", "seed.batch4096", "--seed", str(BIG_SEED),
+# the first cell at full size on the CPU
+ARGS = ["--workload", SPEC["workloads"][0]["name"], "--seed", str(BIG_SEED),
         "--seconds", "2", "--trace", "0"]
 PROGRAM = {"PYTHONPATH": harness.CHECKOUT}
 
@@ -82,3 +83,24 @@ def test_cli_without_the_program(tmp_path):
     proc = _cli(checkout, {"BENCH_PLATFORM": "cpu", "PYTHONPATH": ""})
     assert proc.returncode != 0
     assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+def test_reference_refuses_the_tree_before_set_up(monkeypatch):
+    """A tree the reference cannot judge (here an HR-scoped rule) fails
+    the run in harness prep, before the program is started."""
+    cell = sorted(SMALL)[0]
+    scoped = {"id": "scoped", "effect": "PERMIT", "target": {"subjects": [
+        {"id": "urn:restorecommerce:acs:names:roleScopingEntity",
+         "value": "urn:restorecommerce:acs:model:organization.Organization"}]}}
+    tree = [{"id": "set", "combining_algorithm": ALG, "policies": [
+        {"id": "policy", "combining_algorithm": ALG, "rules": [scoped]}]}]
+    monkeypatch.setattr(harness.trees, "build", lambda *_: tree)
+
+    def no_program(*_):
+        raise AssertionError("the program was started")
+
+    monkeypatch.setattr(harness, "Serving", no_program)
+    with pytest.raises(harness.reference.Unsupported):
+        harness.run(cell, BIG_SEED, 1.0, False, allow_cpu=True,
+                    scale=SMALL[cell], spec=SPEC)
+

@@ -15,7 +15,7 @@ SPAN_METRICS = ("device_dispatch_us_per_row.batch",
 
 
 def reading(stages: dict, rows: int = 40_000, seconds: float = 10.0):
-    cell = harness.Cell("seed.batch4096", 1, {}, {"clients": 2}, [], [])
+    cell = harness.Cell("a-cell", 1, {}, {"clients": 2}, [], [])
     counters = {"paths": {}, "stages": stages}
     return harness.Reading(cell, seconds, {}, {"rows_driven": rows},
                            counters, None)

@@ -5,15 +5,12 @@ Nothing here depends on the program but its wire schema.
 
 A mix (closed loop): ``clients`` threads send ``IsAllowedBatch`` calls of
 ``batch`` rows, cycling through a ``pool`` of batches made from the seed.
-Row kinds (``rows.kind``):
-
-- ``seed_roles``: after ``bench_all._serving_batch_msg``'s default
-  (seed-tree) mix: even rows carry the super-admin role, odd rows
-  role-(i % ``roles``), on the organization entity, the 4 actions in turn;
-  subject ids are drawn Zipf(``zipf``) over ``subjects`` users and each
-  request reads its subject's own record (``res-<subject>``).  With
-  sequential ids in place of the Zipf draws the rows give ``bench_all``'s
-  bytes (checked once in PR 22, see PERF.md).
+Its rows come from the row kind ``rows.kind`` names: the module
+``row_kinds/<kind>.py``, whose ``rows(n, g, params)`` returns ``n`` row
+dicts drawn from ``g`` (``params`` is the mix's ``rows`` block).  A row is
+what ``row`` builds: target attribute lists, and a context whose subject
+and resources go on the wire as JSON, so role-association attributes,
+``hierarchical_scopes`` and ``meta.owners`` pass through as they are.
 """
 
 from __future__ import annotations
@@ -22,6 +19,8 @@ import json
 import os
 
 import numpy as np
+
+import parts
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -46,8 +45,10 @@ def rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([seed % 2**64, stream])
 
 
-def _row(role, subject, entity, resource, action, ctx_subject,
-         ctx_resources) -> dict:
+def row(role, subject, entity, resource, action, ctx_subject,
+        ctx_resources) -> dict:
+    """One request: its target's role, subject, entity, resource and
+    action, and its context's subject and resources (plain dicts)."""
     return {
         "target": {
             "subjects": [{"id": ROLE, "value": role},
@@ -67,25 +68,11 @@ def zipf_draws(g: np.random.Generator, n_items: int, theta: float,
     return g.choice(n_items, size=size, p=weights / weights.sum())
 
 
-def seed_roles_rows(n: int, g: np.random.Generator, p: dict) -> list:
-    users = zipf_draws(g, p["subjects"], p["zipf"], n)
-    rows = []
-    for i in range(n):
-        role = SUPER_ADMIN if i % 2 == 0 else f"role-{i % p['roles']}"
-        user = f"u{int(users[i])}"
-        subject = {"id": user,
-                   "role_associations": [{"role": role, "attributes": []}],
-                   "hierarchical_scopes": []}
-        rows.append(_row(role, user, ORG, f"res-{int(users[i])}",
-                         ACTIONS[i % 4], subject, []))
-    return rows
-
-
-ROW_KINDS = {"seed_roles": seed_roles_rows}
-
-
-def make_rows(mix: dict, n: int, g: np.random.Generator) -> list:
-    return ROW_KINDS[mix["rows"]["kind"]](n, g, mix["rows"])
+def make_pool(mix: dict, g: np.random.Generator) -> list:
+    """``pool`` batches of ``batch`` rows of the mix's row kind."""
+    kind = parts.load("row_kinds", mix["rows"]["kind"])
+    return [kind.rows(mix["batch"], g, mix["rows"])
+            for _ in range(mix["pool"])]
 
 
 # ------------------------------------------------------------------ wire
