@@ -16,6 +16,7 @@ Environments without a toolchain fall back to the Python encoder
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import hashlib
 import os
@@ -436,21 +437,28 @@ class NativeBatchEncoder:
     def encode_wire(self, messages: list[bytes],
                     caps: dict[str, int] | None = None,
                     reuse: bool = False,
-                    relation_tables: dict | None = None) -> RequestBatch:
+                    relation_tables: dict | None = None,
+                    owner_bits_stage=None) -> RequestBatch:
         """Encode serialized acstpu.Request messages.
 
         ``caps`` overrides the per-request padding shapes (the floor
         defaults otherwise).  Rows that were ineligible ONLY because a
-        cap overflowed come back flagged in ``batch.overcap`` — the
-        serving path re-encodes exactly those rows at the ceiling shapes
-        (ops/encode._CAPS_CEIL) so deep-HR wire traffic stays native.
+        cap overflowed come back flagged in ``batch.overcap`` (an
+        ``ops/encode.OVERCAP_*`` code) — the serving path re-encodes
+        exactly those rows at ``ops/encode.retry_caps`` of their codes so
+        deep-HR wire traffic stays native.
 
         ``reuse=True`` draws every buffer (row arrays, masks, regex
         matrices, owner bits) from the staging arenas and attaches a
         ``batch.staging`` release callable — the depth-N pipeline fires
         it after materialize, after which the buffers recycle.  The
         default allocates fresh (callers that hold batches indefinitely
-        must not pin arena slots)."""
+        must not pin arena slots).
+
+        ``owner_bits_stage`` is a context manager entered around the
+        owner-bitplane pack: the serving path passes its tracer's
+        ``wire.owner_bits`` stage block, so the pack is timed apart from
+        the rest of the encode."""
         from ..ops.kernel import pow2_bucket
 
         B = len(messages)
@@ -536,9 +544,10 @@ class NativeBatchEncoder:
         # Python packer ops/encode.pack_owner_bitplanes — structural for
         # trees without HR targets, fuzz-checked with them)
         arrays = dict(a)  # the arena keeps its canonical row-array dict
-        arrays.update(self.owner_bits_native(
-            a, B, take=take if reuse else None
-        ))
+        with owner_bits_stage or contextlib.nullcontext():
+            arrays.update(self.owner_bits_native(
+                a, B, take=take if reuse else None
+            ))
         # relation closure bitplanes (dummies for relation-free trees;
         # fail-closed empties when no store table was supplied)
         arrays.update(self.relation_bits_native(
@@ -562,6 +571,6 @@ class NativeBatchEncoder:
             cond_code=np.full((C, B), 200, np.int32),
             eligible=eligible.view(np.bool_),
             requests=[],
-            overcap=overcap.view(np.bool_),
+            overcap=overcap,
             staging=release,
         )

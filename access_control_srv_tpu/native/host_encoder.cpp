@@ -34,7 +34,7 @@ constexpr int32_t ABSENT = -1;
 // padding caps: runtime parameters of acs_enc_batch (13 int32s in the
 // order of ops/encode._CAPS_FLOOR); null means the floor defaults below.
 // The serving path encodes at the floor first and re-encodes over-cap
-// rows (flagged via the overcap output) at the ceiling shapes, so deep-HR
+// rows (flagged via the overcap output) at wider shapes, so deep-HR
 // traffic stays on the native fast path instead of falling to the oracle.
 struct Caps {
   int NR = 4, NI = 4, NP = 8, NSUB = 8, NACT = 4, NOP = 2;
@@ -484,7 +484,8 @@ struct OutArrays {
   int32_t* r_subject_id;     // [B]
   uint8_t* eligible;         // [B]
   int32_t* batch_entities;   // [B * NR] distinct entity interner ids out
-  uint8_t* overcap;          // [B] ineligible ONLY because a cap overflowed
+  uint8_t* overcap;          // [B] ineligible ONLY because a cap overflowed:
+                             // 2 = only the HR-scope pairs (NHR), 1 = other
 };
 
 // entity tail: URN segment after the last ':' -- the reference's
@@ -1098,11 +1099,14 @@ int32_t acs_enc_batch(void* h, const uint8_t* buf, const int64_t* offs,
     if (!hs_missing)
       flatten_acl_hr(enc, hierarchical_scopes, false, std::string_view(),
                      acl_hr_enc, hr_roles);
-    if ((int)ra3.size() > NRA || (int)ra2.size() > NRA ||
-        (int)hr_enc.size() > NHR || (int)acl_hr_enc.size() > NHR ||
-        (int)hr_roles.size() > NHRR || overflow) {
+    // the last cap check of a row: every earlier one fit, so a row over
+    // NHR alone is told apart for the serving path's cheaper re-encode
+    bool over_hr = (int)hr_enc.size() > NHR || (int)acl_hr_enc.size() > NHR;
+    bool over_rest = (int)ra3.size() > NRA || (int)ra2.size() > NRA ||
+                     (int)hr_roles.size() > NHRR || overflow;
+    if (over_hr || over_rest) {
       o.eligible[b] = 0;
-      o.overcap[b] = 1;
+      o.overcap[b] = over_rest ? 1 : 2;
       continue;
     }
     for (size_t j = 0; j < ra3.size(); ++j)

@@ -53,7 +53,7 @@ from .interner import ABSENT
 # to the scalar oracle, while common traffic keeps one compiled shape.
 # The native (C++) wire encoder takes the same caps at runtime: the wire
 # path encodes at the floor and re-encodes over-cap rows (batch.overcap)
-# at _CAPS_CEIL, so deep rows stay on the native fast path too.
+# at retry_caps, so deep rows stay on the native fast path too.
 NR = 4      # entity runs
 NI = 4      # resource instances
 NP = 8      # property attributes
@@ -78,6 +78,19 @@ _CAPS_CEIL = {
     "NOWN": 32, "NRA": 128, "NHR": 1024, "NROLE": 16, "NACLE": 16,
     "NACLI": 64, "NHRR": 32,
 }
+# batch.overcap codes of the native wire encoder: a row over the flattened
+# HR-scope pairs cap alone, or over any other cap
+OVERCAP_OTHER, OVERCAP_NHR = 1, 2
+
+
+def retry_caps(overcap_codes) -> dict[str, int]:
+    """Caps for the wire path's re-encode of over-cap rows: where every row
+    overflowed NHR alone (deep HR trees), the floor with only NHR at the
+    ceiling, since the matcher's device work scales with the other
+    dimensions (entity runs above all); else the ceiling everywhere."""
+    if all(int(c) == OVERCAP_NHR for c in overcap_codes):
+        return {**_CAPS_FLOOR, "NHR": _CAPS_CEIL["NHR"]}
+    return dict(_CAPS_CEIL)
 
 
 def _pow2_at_least(n: int, floor: int) -> int:
@@ -230,7 +243,8 @@ class RequestBatch:
     # operation_status.message, recovered without an oracle re-run)
     cond_msg: dict = field(default_factory=dict)
     # rows ineligible ONLY because a padding cap overflowed (native wire
-    # encoder); the serving path re-encodes them at the ceiling shapes
+    # encoder; uint8 OVERCAP_* codes, 0 where the row fit); the serving
+    # path re-encodes them at retry_caps
     overcap: Optional[np.ndarray] = None
     # pooled-staging lease (native zero-copy encode): a zero-arg callable
     # returning this batch's buffers to their arena.  MUST only run after

@@ -51,6 +51,8 @@ from .tracing import (
     STAGE_ORACLE,
     STAGE_PREPARE,
     STAGE_WIRE_ENCODE,
+    STAGE_WIRE_ENCODE_CEIL,
+    STAGE_WIRE_OWNER_BITS,
     untimed,
 )
 from .watchdog import DeviceTimeoutError
@@ -907,7 +909,8 @@ class HybridEvaluator:
         with stage(STAGE_WIRE_ENCODE, span):
             rel_tables = self._relation_tables_native(encoder)
             batch = encoder.encode_wire(
-                messages, reuse=reuse, relation_tables=rel_tables
+                messages, reuse=reuse, relation_tables=rel_tables,
+                owner_bits_stage=stage(STAGE_WIRE_OWNER_BITS),
             )
         t_device = time.perf_counter()
         # device.dispatch + device.wait split the ``device`` stage at the
@@ -926,37 +929,42 @@ class HybridEvaluator:
                               time.perf_counter() - t_device)
             if batch.overcap is not None and batch.overcap.any():
                 # adaptive caps, native path: rows that overflowed the
-                # floor shapes re-encode natively at the ceiling (one
-                # extra native call + one extra kernel dispatch for the
-                # rare deep rows) instead of falling back to the oracle
-                from ..ops.encode import _CAPS_CEIL
+                # floor shapes re-encode natively at the caps their
+                # overflow asks for (one extra native call + one extra
+                # kernel dispatch when any of them then fits) instead of
+                # falling back to the oracle
+                from ..ops.encode import retry_caps
 
                 idx = [
                     b for b in range(len(messages))
                     if batch.overcap[b] and not batch.eligible[b]
                 ]
-                retry = encoder.encode_wire(
-                    [messages[b] for b in idx], caps=dict(_CAPS_CEIL),
-                    relation_tables=rel_tables,
-                )
-                with stage(STAGE_DEVICE_DISPATCH):
-                    materialize_retry = self._guard_materialize(
-                        kernel.evaluate_async(retry)
+                self._count_path("native-wire-overcap", len(idx))
+                with stage(STAGE_WIRE_ENCODE_CEIL, span):
+                    retry = encoder.encode_wire(
+                        [messages[b] for b in idx],
+                        caps=retry_caps(batch.overcap[idx]),
+                        relation_tables=rel_tables,
+                        owner_bits_stage=stage(STAGE_WIRE_OWNER_BITS),
                     )
-                with stage(STAGE_DEVICE_WAIT):
-                    d2, c2, s2 = materialize_retry()[:3]
-                # kernel outputs are read-only views on device buffers
-                decision = np.array(decision)
-                cacheable = np.array(cacheable)
-                status = np.array(status)
-                n_retried = 0
-                for j, b in enumerate(idx):
-                    if retry.eligible[j]:
-                        batch.eligible[b] = True
-                        decision[b] = d2[j]
-                        cacheable[b] = c2[j]
-                        status[b] = s2[j]
-                        n_retried += 1
+                n_retried = int(retry.eligible.sum())
+                if n_retried:
+                    with stage(STAGE_DEVICE_DISPATCH):
+                        materialize_retry = self._guard_materialize(
+                            kernel.evaluate_async(retry)
+                        )
+                    with stage(STAGE_DEVICE_WAIT):
+                        d2, c2, s2 = materialize_retry()[:3]
+                    # kernel outputs are read-only views on device buffers
+                    decision = np.array(decision)
+                    cacheable = np.array(cacheable)
+                    status = np.array(status)
+                    for j, b in enumerate(idx):
+                        if retry.eligible[j]:
+                            batch.eligible[b] = True
+                            decision[b] = d2[j]
+                            cacheable[b] = c2[j]
+                            status[b] = s2[j]
                 self._count_path("native-wire-ceil", n_retried)
             n_served = sum(
                 1 for b in range(len(messages))

@@ -87,6 +87,8 @@ STAGE_PREPARE = "prepare"                    # token resolve / HR / prefetch
 STAGE_CACHE = "cache.lookup"                 # decision-cache consult (hits)
 STAGE_ENCODE = "encode"                      # request -> kernel arrays
 STAGE_WIRE_ENCODE = "wire.encode"            # native C++ wire encode
+STAGE_WIRE_OWNER_BITS = "wire.owner_bits"    # owner-bitplane pack within it
+STAGE_WIRE_ENCODE_CEIL = "wire.encode.ceil"  # over-cap rows at ceiling caps
 STAGE_DEVICE = "device"                      # H2D + device eval + D2H
 STAGE_DEVICE_DISPATCH = "device.dispatch"    # host prep + H2D + launch
 STAGE_DEVICE_WAIT = "device.wait"            # blocked on result + D2H
@@ -98,8 +100,9 @@ STAGE_GC = "gc"                              # garbage-collector pause
 STAGES = (
     STAGE_RPC, STAGE_TRANSPORT_PARSE, STAGE_ADMISSION, STAGE_QUEUE_WAIT,
     STAGE_PREPARE, STAGE_CACHE, STAGE_ENCODE, STAGE_WIRE_ENCODE,
-    STAGE_DEVICE, STAGE_DEVICE_DISPATCH, STAGE_DEVICE_WAIT, STAGE_DECODE,
-    STAGE_ORACLE, STAGE_SERIALIZE, STAGE_GC,
+    STAGE_WIRE_OWNER_BITS, STAGE_WIRE_ENCODE_CEIL, STAGE_DEVICE,
+    STAGE_DEVICE_DISPATCH, STAGE_DEVICE_WAIT, STAGE_DECODE, STAGE_ORACLE,
+    STAGE_SERIALIZE, STAGE_GC,
 )
 
 

@@ -470,6 +470,105 @@ def test_wire_path_serves_deep_hr_rows_via_ceiling():
         assert decision[b] == DEC_CODE[expected.decision], b
 
 
+def _wide_deep_hr_request():
+    """A deep-HR request that also overflows another cap: five entity runs
+    against the floor's four."""
+    from access_control_srv_tpu.models import Attribute
+
+    from .utils import URNS
+
+    wide = _deep_hr_request(64, owner="org-40-n")
+    loc = "urn:restorecommerce:acs:model:location.Location"
+    owned = wide.context["resources"][0]
+    wide.target.resources = []
+    wide.context["resources"] = []
+    for i in range(5):
+        wide.target.resources += [
+            Attribute(id=URNS["entity"], value=loc),
+            Attribute(id=URNS["resourceID"], value=f"L{i}")]
+        wide.context["resources"].append({**owned, "id": f"L{i}"})
+    return wide
+
+
+def _spied_evaluator():
+    """A role_scopes evaluator with its native encode_wire and kernel
+    dispatch recorded: (evaluator, telemetry, encode calls, dispatches)."""
+    from access_control_srv_tpu.srv.evaluator import HybridEvaluator
+    from access_control_srv_tpu.srv.telemetry import Telemetry
+
+    engine = make_engine("role_scopes.yml")
+    telemetry = Telemetry()
+    ev = HybridEvaluator(engine, telemetry=telemetry)
+    if not ev.native_active:
+        pytest.skip("native encoder not active for this tree")
+    calls, dispatches = [], []
+    encode_wire = ev._native_encoder.encode_wire
+    evaluate_async = ev._kernel.evaluate_async
+
+    def spy_encode(msgs, caps=None, **kw):
+        calls.append((len(msgs), caps))
+        return encode_wire(msgs, caps=caps, **kw)
+
+    def spy_dispatch(batch):
+        dispatches.append(batch.B)
+        return evaluate_async(batch)
+
+    ev._native_encoder.encode_wire = spy_encode
+    ev._kernel.evaluate_async = spy_dispatch
+    return engine, ev, telemetry, calls, dispatches
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["nhr_only", "wide"])
+def test_wire_path_retries_deep_hr_rows_before_the_ceiling(wide):
+    """Over-cap rows re-encode once, at the caps their overflow asks for:
+    rows over the HR-scope pair cap alone at the floor with only NHR at the
+    ceiling; with a row over another cap among them, every row at the full
+    ceiling.  Both are served natively, with the oracle's decisions."""
+    from access_control_srv_tpu.ops.encode import (
+        _CAPS_CEIL, _CAPS_FLOOR, OVERCAP_NHR, OVERCAP_OTHER,
+    )
+
+    engine, ev, telemetry, calls, dispatches = _spied_evaluator()
+    reqs = [_deep_hr_request(64, owner="org-40-n"),
+            _deep_hr_request(3, owner="org-1-n")]
+    if wide:
+        reqs.append(_wide_deep_hr_request())
+    messages, twins = wire_roundtrip(reqs)
+    floor = ev._native_encoder.encode_wire(messages)
+    codes = [OVERCAP_NHR, 0] + ([OVERCAP_OTHER] if wide else [])
+    assert floor.overcap.tolist() == codes
+    calls.clear()
+    batch, decision, _, status = ev.is_allowed_batch_wire(messages)
+    caps = (dict(_CAPS_CEIL) if wide
+            else {**_CAPS_FLOOR, "NHR": _CAPS_CEIL["NHR"]})
+    n_over = 2 if wide else 1
+    assert calls == [(len(reqs), None), (n_over, caps)]
+    assert dispatches == [len(reqs), n_over]
+    assert bool(batch.eligible.all())
+    assert telemetry.paths.get("native-wire-overcap") == n_over
+    assert telemetry.paths.get("native-wire-ceil") == n_over
+    for b, req in enumerate(twins):
+        expected = engine.is_allowed(req)
+        assert decision[b] == DEC_CODE[expected.decision], b
+        assert status[b] == 200
+
+
+def test_wire_path_skips_the_retry_dispatch_when_no_row_fits():
+    """A row over even the ceiling (more HR-scope pairs than its 1024)
+    stays ineligible for the oracle, and the retry then dispatches
+    nothing to the device."""
+    _, ev, telemetry, calls, dispatches = _spied_evaluator()
+    messages, _ = wire_roundtrip([_deep_hr_request(1100),
+                                  _deep_hr_request(3, owner="org-1-n")])
+    batch, _, _, status = ev.is_allowed_batch_wire(messages)
+    assert [n for n, _ in calls] == [2, 1]
+    assert dispatches == [2]
+    assert batch.eligible.tolist() == [False, True]
+    assert status[1] == 200
+    assert telemetry.paths.get("native-wire-overcap") == 1
+    assert telemetry.paths.get("native-wire-ceil") == 0
+
+
 # ------------------------------------------------- owner-bit packer parity
 
 
