@@ -459,6 +459,34 @@ def _subject_ok(c: dict, r: dict):
     )
 
 
+def _regex_entity_lookup(c: dict, r: dict, ent_valid):
+    """``rgx_set`` and ``pfx_neq`` read at (target row's entity-vocab row,
+    request run's batch entity column) -> two [T, K_ENT, NR] bool, masked
+    to valid target slots and runs.
+
+    The [W, E] tables are per batch and the vocab rows per tree, so the
+    [T, K_ENT, E] rows are read once per batch; each run then picks its
+    column with a one-hot contraction over E.  Under the dense kernel's
+    vmap that is one [B * NR, E] x [E, 2 * T * K_ENT] matmul, where
+    indexing by (w, e) would be a scalar gather of B * T * K_ENT * NR
+    elements per table.  Exact: every product is 0 or 1 and one term of
+    each sum at most is non-zero.  Columns clip to [0, E - 1], as the
+    gather clamps them."""
+    tables = jnp.stack([r["rgx_set"], r["pfx_neq"]])  # [2, W, E]
+    E = tables.shape[-1]
+    rows = tables[:, jnp.clip(c["t_ent_w"], 0, None)]  # [2, T, K_ENT, E]
+    e_idx = jnp.clip(r["r_ent_e"], 0, E - 1)  # [NR]
+    onehot = e_idx[:, None] == jnp.arange(E)[None, :]  # [NR, E]
+    hits = jnp.einsum(
+        "stke,ne->stkn",
+        rows.astype(jnp.bfloat16),
+        onehot.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ) > 0
+    valid = (c["t_ent_w"][:, :, None] >= 0) & ent_valid[None, None, :]
+    return hits[0] & valid, hits[1] & valid
+
+
 def _match_targets(c: dict, r: dict, with_hr: bool = True,
                    wia: bool = False, components: bool = False,
                    with_rel: bool = False):
@@ -509,13 +537,8 @@ def _match_targets(c: dict, r: dict, with_hr: bool = True,
         & (c["t_ent_vals"][:, :, None] >= 0)
         & ent_valid[None, None, :]
     ).any(axis=1)  # [T, NR]
-    w_idx = jnp.clip(c["t_ent_w"], 0, None)  # [T, K_ENT]
-    e_idx = jnp.clip(r["r_ent_e"], 0, None)  # [NR]
-    rgx_hit = r["rgx_set"][w_idx[:, :, None], e_idx[None, None, :]]  # [T,K,NR]
-    rgx_hit = rgx_hit & (c["t_ent_w"][:, :, None] >= 0) & ent_valid[None, None, :]
+    rgx_hit, pfx_neq = _regex_entity_lookup(c, r, ent_valid)  # [T, K, NR]
     em_rg = rgx_hit.any(axis=1)  # [T, NR]
-    pfx_neq = r["pfx_neq"][w_idx[:, :, None], e_idx[None, None, :]]
-    pfx_neq = pfx_neq & (c["t_ent_w"][:, :, None] >= 0) & ent_valid[None, None, :]
 
     ent_any_ex = em_ex.any(axis=1)  # [T]
     ent_any_rg = em_rg.any(axis=1)

@@ -1269,11 +1269,13 @@ def main() -> int:
     })
 
     # ---- rebac-zero-matmul-program-identity: the ReBAC serving claims
-    # (docs/REBAC.md).  (a) the relation-bearing device program is pure
-    # bit-reading — ZERO dot_general ops in its HLO (the Zanzibar closure
-    # is folded on the host into int32 bitplanes; the kernel only masks
-    # and shifts); (b) relation-tuple CRUD swaps NO program: jit registry
-    # keys, per-key executable caches and the compiled-table version are
+    # (docs/REBAC.md).  (a) the relation fold is pure bit-reading: it
+    # adds no dot_general to the dense program, whose one is the
+    # matcher's regex-entity lookup (ops/kernel._regex_entity_lookup;
+    # the Zanzibar closure is folded on the host into int32 bitplanes;
+    # the kernel only masks and shifts); (b) relation-tuple CRUD swaps NO
+    # program: jit registry keys, per-key executable caches and the
+    # compiled-table version are
     # all byte-stable across a create/delete cycle that flips the served
     # decision; (c) two stores on one bus (writer + replicating reader)
     # converge to byte-identical tuple fingerprints, so replicas keep the
@@ -1357,7 +1359,7 @@ def main() -> int:
     writer_r.delete([(doc_r, "doc0", "owner", "u0")])
     replica_identical = writer_r.fingerprint() == reader_r.fingerprint()
 
-    rebac_ok = (dot_generals == 0 and churn_ok and replica_identical)
+    rebac_ok = (dot_generals == 1 and churn_ok and replica_identical)
     results.append({
         "kernel": "rebac-zero-matmul-program-identity",
         "ok": bool(rebac_ok),
@@ -1365,9 +1367,10 @@ def main() -> int:
         "churn_zero_new_xla_compiles": bool(churn_ok),
         "churn_decision_flip": [dec_closed, dec_open, dec_reclosed],
         "replica_tuple_fingerprint_identical": bool(replica_identical),
-        "note": ("the relation-bearing dense program contains zero "
-                 "dot_general ops (the Zanzibar closure is host-folded "
-                 "into bitplanes; the device side is the stage-B bit "
+        "note": ("the relation-bearing dense program's one dot_general "
+                 "is the regex-entity lookup's; the relation fold adds "
+                 "none (the Zanzibar closure is host-folded into "
+                 "bitplanes; the device side is the stage-B bit "
                  "reader); tuple create/delete flips the served decision "
                  "with jit keys, executable caches and the compiled "
                  "version all byte-stable; a replicating store converges "
