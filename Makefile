@@ -1,6 +1,6 @@
 # Convenience targets; verify.sh is the source of truth for the gate.
 
-.PHONY: verify test lint audit bench smoke
+.PHONY: verify test lint smoke
 
 verify:
 	./verify.sh
@@ -12,12 +12,6 @@ test:
 
 lint:
 	python -m access_control_srv_tpu.analysis
-
-audit:
-	BENCH_PLATFORM=cpu python tpu_compat_audit.py
-
-bench:
-	python bench_all.py
 
 smoke:
 	python chip_smoke.py

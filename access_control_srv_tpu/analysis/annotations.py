@@ -24,8 +24,9 @@ comments so annotated modules carry zero import-time cost:
 ``# acs-lint: host-only``
     Anywhere in a module: declares the module host-only — any ``jax``
     import (even lazy, inside a function) becomes a finding.  The
-    declaration living in the module itself is what lets
-    TPU_COMPAT.md's host-only claims cite a machine-checked rule.
+    declaration living in the module itself is what makes a module's
+    host-only claim a machine-checked rule
+    (tests/test_analysis_gate.py::test_host_only_modules_declare_the_marker).
 """
 
 from __future__ import annotations

@@ -225,7 +225,7 @@ class ModuleChecker(ast.NodeVisitor):
                 f"{self._qualname()}:import {modname}",
                 "module is declared `# acs-lint: host-only` but imports "
                 "jax — host-only modules must never touch the device "
-                "runtime (TPU_COMPAT.md zero-device-ops rows)",
+                "runtime (tests/test_analysis_gate.py host-only modules)",
                 node,
             )
 

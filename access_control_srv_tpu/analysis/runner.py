@@ -1,7 +1,7 @@
 """acs-lint runner: walk a tree, run every pass, gate on the baseline.
 
-``run_analysis`` is the library entry (used by tests and the
-``static-invariants-clean`` audit row); ``__main__`` wraps it as
+``run_analysis`` is the library entry (used by
+tests/test_analysis_gate.py); ``__main__`` wraps it as
 ``python -m access_control_srv_tpu.analysis``.
 """
 

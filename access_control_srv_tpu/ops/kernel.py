@@ -1309,8 +1309,8 @@ class DecisionKernel:
         bucketed for the same reason)."""
         # failpoints (srv/faults.py): host-side only — fired before the
         # jitted call / inside the materialize thunk, so the lowered
-        # device program is byte-identical with faults configured
-        # (tpu_compat_audit.py failpoints-zero-device-ops)
+        # device program is the same with faults armed
+        # (tests/test_delta.py::TestHostSideSubsystems, failpoints)
         from ..srv.faults import REGISTRY as _faults
 
         _faults.fire("device.dispatch")

@@ -190,7 +190,7 @@ class LatticeSpec:
         entities: int = 64,
         urns=None,
     ) -> "LatticeSpec":
-        """The synthetic-stress-tree lattice (bench_all._stress_doc):
+        """The synthetic-stress-tree lattice (synth.stress_doc):
         subjects cycle ``role-{0..roles-1}``, resources cycle the
         ``stress{0..entities-1}`` entity types, actions resolve through
         the URN registry (bare names like ``"read"`` or full URNs)."""

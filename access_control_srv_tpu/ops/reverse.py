@@ -45,9 +45,9 @@ _PLANE_KEYS = [
     "sig_maybe_ex", "sig_maybe_rg", "sig_act_ok",
 ]
 
-# below this rule count the scalar reverse-query walk beats the device
-# round-trip (measured: seed tree scalar ~6x kernel, ~1000-rule tree kernel
-# 3-12x scalar — bench_all.py wia rows); mirrors ops/prefilter.MIN_RULES
+# below this rule count the scalar reverse-query walk is taken to beat the
+# device round-trip (no benchmark cell measures the crossover); mirrors
+# ops/prefilter.MIN_RULES
 REVERSE_MIN_RULES = 512
 
 

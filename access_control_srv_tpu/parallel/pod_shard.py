@@ -18,8 +18,7 @@ stable slots (``apply_events`` never moves a set's slot, and target rows
 are owned per set via ``target_owners``), so slot ownership is stable
 under churn and a CRUD event touching one set re-slices exactly one
 shard.  The unaffected shards' host tables are reused BY REFERENCE —
-byte-identical, as the audit row `sharded-tree-program-identity` asserts
-— and the jitted shard_map program is registered in the evaluator's
+byte-identical, as tests/test_pod_shard.py asserts — and the jitted shard_map program is registered in the evaluator's
 shared-jit table, so an in-capacity patch costs ZERO new XLA compiles on
 any shard.
 

@@ -5,8 +5,9 @@ The PDP sits on the request critical path of every protected service
 call), so overload must turn into CONTROLLED degradation — bounded
 queues, early shedding, deadline-aware rejection — never unbounded
 queueing and timeout storms.  This module is the host-side brain of that
-behavior; it owns ZERO device state and never imports jax (asserted by
-tpu_compat_audit.py row ``admission-zero-device-ops``).
+behavior; it owns ZERO device state and never imports jax (the acs-lint
+host-only rule; admitted rows run the plain rows' device programs,
+tests/test_delta.py::TestHostSideSubsystems).
 
 Pieces, all consumed by ``srv/batcher.MicroBatcher`` and the transports:
 
@@ -44,7 +45,7 @@ tests/test_admission.py's differential check).
 from __future__ import annotations
 
 # acs-lint: host-only — admission decisions must stay off the device
-# runtime (tpu_compat_audit row admission-zero-device-ops)
+# runtime
 
 import random
 import re

@@ -73,6 +73,9 @@ class SweepJob:
         self.wall_s: Optional[float] = None
         self._lock = threading.Lock()
         self._paused = threading.Event()
+        # set by the sweep thread while it waits out a pause, with no
+        # chunk in flight: from then on cells_done cannot move
+        self._parked = threading.Event()
         self._cancel = threading.Event()
         self._finished = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -89,6 +92,7 @@ class SweepJob:
                 "sheds": self.sheds,
                 "retries": self.retries,
                 "paused": self._paused.is_set(),
+                "parked": self._parked.is_set(),
                 "snapshot": self.snapshot_path,
                 "bitmap": self.bitmap_path,
                 "policy_epoch": self.policy_epoch,
@@ -346,7 +350,9 @@ class AuditSweepManager:
                 shadow_eval = shadow.evaluator
             for chunk in job.spec.chunks(self.chunk_size):
                 while job._paused.is_set() and not job._cancel.is_set():
+                    job._parked.set()
                     time.sleep(0.02)
+                job._parked.clear()
                 if job._cancel.is_set():
                     break
                 if shadow_eval is not None:

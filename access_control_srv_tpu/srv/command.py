@@ -356,9 +356,10 @@ class CommandInterface:
         """Cluster-tier convergence probe: the replica's policy epoch plus
         a digest of its compiled policy tables (srv/evaluator.py
         table_fingerprint).  Two replicas that applied the same CRUD
-        sequence report identical fingerprints — the chaos harness and the
-        tpu_compat_audit ``cluster-replica-program-identity`` row compare
-        these across independently-patched processes."""
+        sequence report identical fingerprints — the chaos harness
+        compares these across independently-patched processes
+        (tests/test_cluster_chaos.py; in one process,
+        tests/test_delta.py::TestProgramReuse)."""
         out: dict = {}
         if self.worker is not None and hasattr(self.worker, "policy_epoch"):
             out["policy_epoch"] = self.worker.policy_epoch()
@@ -371,8 +372,8 @@ class CommandInterface:
             # pod-sharded tier: the per-shard fingerprints roll into one
             # pod fingerprint (already folded into table_fingerprint
             # above, so the cluster convergence oracle checks it
-            # transparently); the full breakdown rides along for the
-            # audit row and operator drill-down
+            # transparently); the full breakdown rides along for
+            # operator drill-down
             sharding = evaluator.shard_identity()
             if sharding is not None:
                 out["sharding"] = sharding

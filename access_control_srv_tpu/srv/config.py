@@ -42,11 +42,6 @@ DEFAULT_CONFIG: dict = {
         # Depth N>2 turns on the dispatch/finalize split: H2D+eval of
         # batch i overlaps prep of i+1 and decode/serialize of i-1.
         "pipeline_depth": 2,
-        # incremental policy updates (ops/delta.py): capacity-bucketed
-        # tables, in-place CRUD patching without XLA recompiles, scoped
-        # decision-cache invalidation.  Disable to force the pre-delta
-        # full-recompile + global-flush behavior on every mutation.
-        "delta_enabled": True,
         # device-hang watchdog (srv/watchdog.py, docs/FAULTS.md).
         # Disabled by default: materialize blocks exactly as before.
         # Enabled: every pipeline/batcher materialize gets a hard
@@ -238,9 +233,10 @@ DEFAULT_CONFIG: dict = {
         "points": [],
     },
     # explain mode (srv/explain.py, docs/EXPLAIN.md).  Disabled by
-    # default: the kernels trace the exact pre-explain computation and
-    # the lowered device programs are byte-identical
-    # (tpu_compat_audit.py explain-shadow-program-identity).  Enabled:
+    # default: the kernels trace the exact pre-explain computation, and
+    # the explain variant keys separately in the shared jit registry
+    # (tests/test_explain.py::test_explain_off_keeps_three_outputs).
+    # Enabled:
     # every kernel row carries one extra int32 naming the deciding node,
     # decoded host-side onto the response (``_rule_id`` matching the
     # oracle's EffectEvaluation.source bit-for-bit, plus the richer

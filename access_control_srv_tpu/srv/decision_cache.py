@@ -43,7 +43,8 @@ Design:
 
 The lookup path is host-only by construction: this module never imports
 jax and a cache hit returns before any encode or device dispatch
-(asserted by tpu_compat_audit.py and tests/test_decision_cache.py).
+(tests/test_decision_cache.py::
+test_batch_cache_hits_never_reach_the_device).
 
 Semantics bar: cache on/off must never change a decision — only responses
 whose ``evaluation_cacheable`` is True (every contributing rule cacheable,
@@ -55,7 +56,7 @@ bit-identical decision streams under randomized CRUD interleavings.
 from __future__ import annotations
 
 # acs-lint: host-only — the lookup path must never touch the device
-# runtime (tpu_compat_audit row decision-cache-lookup)
+# runtime
 
 import threading
 import time

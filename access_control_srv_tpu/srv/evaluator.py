@@ -71,7 +71,6 @@ class HybridEvaluator:
         model_axis: str | None = None,
         pod_shards: int | None = None,
         decision_cache=None,
-        delta_enabled: bool = True,
         observability=None,
         shared_jits: Optional[dict] = None,
         fixed_caps=None,
@@ -138,8 +137,7 @@ class HybridEvaluator:
         # path keeps it ON: PodShardedKernel.patched re-slices only the
         # shards owning the patched set slots.
         self.delta_enabled = bool(
-            delta_enabled
-            and (model_axis is None or pod_shards is not None)
+            (model_axis is None or pod_shards is not None)
             and backend != "oracle"
         )
         self._caps = None                   # delta_mod.Capacities
@@ -429,7 +427,8 @@ class HybridEvaluator:
         sequence through the delta path hold byte-identical tables, so
         their fingerprints match — the cluster tier's convergence check
         (srv/router.py health, tests/test_cluster_chaos.py,
-        tpu_compat_audit cluster-replica-program-identity)."""
+        tests/test_delta.py::TestProgramReuse::
+        test_replicas_replaying_one_journal_hold_identical_tables)."""
         from hashlib import blake2b
 
         compiled = self._compiled
@@ -667,7 +666,8 @@ class HybridEvaluator:
         decision cache — tuple churn changes decisions without any policy
         CRUD, but swaps NO program: the compiled tables, the kernel and
         every jitted executable stay byte-identical (the ReBAC serving
-        invariant, tpu_compat_audit rebac-zero-matmul-program-identity)."""
+        invariant, tests/test_relations.py::
+        test_tuple_churn_swaps_no_program)."""
         self.engine.relation_store = store
 
         def _on_change(_gen: int) -> None:
@@ -1214,9 +1214,9 @@ class HybridEvaluator:
         only serve isAllowed never pay its device transfer).
 
         Dispatch is adaptive like the decision path's MIN_RULES: on small
-        trees the scalar walk beats the device round-trip (measured ~6x on
-        the seed tree, bench_all.py wia row), so the kernel only engages at
-        REVERSE_MIN_RULES and above."""
+        trees the scalar walk is taken to beat the device round-trip (no
+        benchmark cell measures the crossover), so the kernel only engages
+        at REVERSE_MIN_RULES and above."""
         from ..ops.reverse import REVERSE_MIN_RULES
 
         self.prepare_batch(requests)

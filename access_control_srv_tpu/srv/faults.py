@@ -1,6 +1,6 @@
 # acs-lint: host-only — fault injection is pure host-side control flow
-# and must never import jax or touch the device program (the
-# failpoints-zero-device-ops audit row depends on it).
+# and must never import jax or touch the device program
+# (tests/test_delta.py::TestHostSideSubsystems depends on it).
 """Deterministic failpoint framework (PR 11).
 
 Named injection sites are threaded through every external and async

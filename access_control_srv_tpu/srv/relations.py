@@ -34,7 +34,7 @@ Tuple churn never touches the compiled policy tensors: the kernel
 consumes relations only through the per-batch bitplanes packed from
 these tables, so an in-capacity tuple write costs a scoped closure
 invalidation + a decision-cache bump — zero new XLA compilations
-(tpu_compat_audit rebac-zero-matmul-program-identity).
+(tests/test_relations.py::test_tuple_churn_swaps_no_program).
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ provenance for BOTH sides, recovered through the host oracle's
 any device-program change, so the invariant below holds even with
 explain mode off).
 
-Honesty invariants (tests/test_explain.py, bench_all.py shadow-diff):
+Honesty invariants (tests/test_explain.py::TestShadow):
 
 - A shadow evaluation can NEVER alter a production decision: the mirror
   point is after response assembly, the shadow engine/evaluator objects
@@ -109,7 +109,6 @@ class ShadowEvaluator:
             model_axis=production.model_axis,
             pod_shards=production.pod_shards,
             decision_cache=None,  # INVARIANT: shadow decisions never cached
-            delta_enabled=production.delta_enabled,
             shared_jits=production._shared_jits,
             fixed_caps=production._caps,
             explain=production.explain,

@@ -8,8 +8,7 @@ compiled tables (ops/delta.Capacities) mean two tenants whose trees pad
 to the SAME capacity class produce byte-identical jitted programs where
 the per-tenant tables are jit *arguments* — so a thousand tenant trees
 serve from at most ``len(SIZE_CLASSES)`` compiled programs instead of a
-thousand XLA compiles (tpu_compat_audit row
-``tenant-packing-program-identity``).
+thousand XLA compiles (tests/test_tenancy.py::TestProgramPacking).
 
 Pieces:
 
@@ -265,7 +264,6 @@ class TenantRegistry:
             logger=self.logger,
             telemetry=self.telemetry,
             decision_cache=self.decision_cache,
-            delta_enabled=True,
             observability=self.observability,
             shared_jits=self._shared_jits,
             fixed_caps=fixed,

@@ -43,9 +43,9 @@ carry the device-recovered deciding rule id through the identical
 ``_rule_id`` attribute and the identical masking path.
 
 Everything here is host-only BY CONSTRUCTION: this module never imports
-jax (statically asserted by tpu_compat_audit.py row
-``tracing-zero-device-ops``), and a traced batch lowers to the
-byte-identical device program as an untraced one.  With the
+jax (the acs-lint host-only rule), and a traced batch runs the
+executables an untraced one compiled
+(tests/test_delta.py::TestHostSideSubsystems).  With the
 ``observability`` config absent the hub is never built and the serving
 path is byte-identical to pre-observability behavior
 (tests/test_tracing.py differential).
@@ -54,8 +54,8 @@ path is byte-identical to pre-observability behavior
 from __future__ import annotations
 
 # acs-lint: host-only — tracing must never import jax; a traced batch
-# lowers to the byte-identical device program (tpu_compat_audit row
-# tracing-zero-device-ops)
+# runs the same device program (tests/test_delta.py
+# TestHostSideSubsystems, tracing)
 
 import gc
 import logging

@@ -368,7 +368,6 @@ class Worker:
             model_axis=model_axis,
             pod_shards=pod_shards,
             decision_cache=self.decision_cache,
-            delta_enabled=bool(cfg.get("evaluator:delta_enabled", True)),
             observability=self.obs,
             # explain mode (srv/explain.py): kernel rows carry deciding-
             # node provenance.  False (the default) lowers the exact

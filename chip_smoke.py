@@ -121,14 +121,14 @@ def load_config(extra: dict | None = None):
 
 def build_workload(n_rules: int, batch: int, calls: int, seed: int):
     """The tree document and ``calls`` HR-scoped wire batches
-    (bench_all's generators, imported, not copied)."""
+    (``synth``'s generators)."""
     import numpy as np
 
-    from bench_all import _serving_batch_msg, _stress_doc
+    from synth import serving_batch_msg, stress_doc
 
-    doc, actual = _stress_doc(n_rules, scoped=True)
+    doc, actual = stress_doc(n_rules, scoped=True)
     rng = np.random.default_rng(seed)
-    msgs = [_serving_batch_msg(batch, rng, hr=True) for _ in range(calls)]
+    msgs = [serving_batch_msg(batch, rng, hr=True) for _ in range(calls)]
     return doc, actual, msgs
 
 
@@ -260,12 +260,12 @@ class Serving:
     def load_tree(self, doc: dict) -> float:
         """The tree through the store's seed path (the PRP path);
         returns seconds.  The compile it starts runs async."""
-        from bench_all import _store_docs
+        from synth import store_docs
 
         t0 = time.perf_counter()
         # a copy: the store owns what it is given, and the next layout
         # loads the same document
-        self.worker.store.seed(*_store_docs(copy.deepcopy(doc)))
+        self.worker.store.seed(*store_docs(copy.deepcopy(doc)))
         return time.perf_counter() - t0
 
     def wait_kernel(self, n_rules: int, timeout_s: float) -> float:

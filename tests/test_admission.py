@@ -806,8 +806,8 @@ class TestAuditSweepStarvation:
     def test_saturating_sweep_cannot_starve_interactive(self, tmp_path):
         """While a full-lattice sweep saturates the bulk queue, every
         admitted interactive request still resolves 200 with p99 well
-        inside the interactive deadline bound (BASELINE.md
-        audit-fairness: p99 <= 500 ms with a 5 ms device step)."""
+        inside the interactive deadline bound (p99 <= 500 ms with a 5 ms
+        device step)."""
         ctl = controller(bulk_interval=4, adaptive_max_batch=False)
         evaluator = StubEvaluator(delay_s=0.005)
         batcher = make_batcher(evaluator, ctl, max_batch=64)

@@ -51,10 +51,12 @@ def test_baseline_entries_all_justified():
 
 
 def test_host_only_modules_declare_the_marker():
-    """The modules TPU_COMPAT.md claims are host-only must carry the
-    self-declaring marker — the claim is machine-checked, not prose."""
+    """The modules the docs call host-only must carry the self-declaring
+    marker, so that acs-lint's host-only rule holds them to it: the claim
+    is machine-checked, not prose."""
     for name in ("srv/tracing.py", "srv/admission.py",
-                 "srv/decision_cache.py", "srv/router.py"):
+                 "srv/decision_cache.py", "srv/router.py", "srv/faults.py",
+                 "ops/lattice.py", "srv/audit_sweep.py"):
         source = (PACKAGE_ROOT / name).read_text()
         assert "acs-lint: host-only" in source, (
             f"{name} lost its host-only declaration"

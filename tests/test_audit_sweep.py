@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 import yaml
 
-import bench_all
+import synth
 from access_control_srv_tpu.core import AccessController
 from access_control_srv_tpu.core.loader import load_policy_sets
 from access_control_srv_tpu.models import Decision
@@ -54,14 +54,14 @@ from access_control_srv_tpu.srv.telemetry import Telemetry
 
 from .test_admission import StubEvaluator, controller, make_batcher
 
-DO = bench_all.DO
-PO = bench_all.PO
+DO = synth.DO
+PO = synth.PO
 
 ALL_ACTIONS = ("read", "modify", "create", "delete")
 
 
 def stress_engine(n_rules=48, flip_every=0):
-    doc, _ = bench_all._stress_doc(n_rules, flip_every=flip_every)
+    doc, _ = synth.stress_doc(n_rules, flip_every=flip_every)
     engine = AccessController()
     for ps in load_policy_sets(doc):
         engine.update_policy_set(ps)
@@ -276,7 +276,7 @@ class TestSnapshot:
 class TestDiff:
     def test_one_rule_flip_names_exactly_the_flipped_cells(self, tmp_path):
         """The acceptance oracle: sweeping a candidate with exactly one
-        rule flipped (bench_all._stress_doc flip_every > rid range flips
+        rule flipped (synth.stress_doc flip_every > rid range flips
         only r0) must diff exactly the cells where the scalar oracle's
         decisions differ, and every diff cell must name r0."""
         engine_a = stress_engine(48)
@@ -408,7 +408,12 @@ class TestSweepManager:
                 assert time.monotonic() < deadline, "sweep never started"
                 time.sleep(0.005)
             manager.pause(job.job_id)
-            time.sleep(0.1)
+            # the sweep acknowledges the pause once the chunk in flight
+            # has landed: only then is cells_done final
+            deadline = time.monotonic() + 10
+            while not job.status()["parked"]:
+                assert time.monotonic() < deadline, "pause never took hold"
+                time.sleep(0.005)
             frozen = job.status()["cells_done"]
             time.sleep(0.15)
             assert job.status()["cells_done"] == frozen, (
@@ -520,7 +525,10 @@ class TestProgramIdentity:
         """Zero new XLA compiles across sweep chunks: after a warm
         sweep, a second identical sweep adds no jit-registry keys and
         keeps the SAME ReverseQueryKernel object (compiled program
-        reuse, the tpu_compat_audit audit-sweep-program-identity row)."""
+        reuse), and XLA compiles nothing for it."""
+        import jax
+        from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
         monkeypatch.setattr(reverse_mod, "REVERSE_MIN_RULES", 0)
         engine = stress_engine(48)
         telemetry = Telemetry()
@@ -539,8 +547,21 @@ class TestProgramIdentity:
             assert kernel is not None, "sweep never engaged the kernel"
             keys_before = set(kernel._runs)
             version_before = kernel.compiled.version
-            job = manager.start_sweep(spec=spec, wait=True,
-                                      wait_timeout=120)
+            # the reverse runners bind their tables into the program, so
+            # compiles are counted where XLA reports them
+            compiles = []
+
+            def count(event, duration, **kwargs):
+                if event == BACKEND_COMPILE_EVENT:
+                    compiles.append(kwargs.get("fun_name"))
+
+            jax.monitoring.register_event_duration_secs_listener(count)
+            try:
+                job = manager.start_sweep(spec=spec, wait=True,
+                                          wait_timeout=120)
+            finally:
+                jax.monitoring.unregister_event_duration_listener(count)
+            assert compiles == []
             assert job.state == "done"
             assert evaluator._rq_kernel is kernel
             assert set(kernel._runs) == keys_before, (
@@ -564,7 +585,7 @@ class TestTwinLoop:
         rule) loads through ShadowEvaluator with zero new compiles, the
         twin sweep diffs the full lattice naming the flipped rule, and
         the same report carries the live-traffic diff counters."""
-        doc_b, _ = bench_all._stress_doc(48, flip_every=10 ** 9)
+        doc_b, _ = synth.stress_doc(48, flip_every=10 ** 9)
         candidate = str(tmp_path / "candidate.yml")
         with open(candidate, "w") as fh:
             yaml.safe_dump(doc_b, fh)

@@ -298,6 +298,63 @@ def worker():
     w.stop()
 
 
+def test_batch_cache_hits_never_reach_the_device():
+    """A warm hit is answered on the host: with the kernel replaced by
+    one that records every call, a repeat batch of cacheable rows is
+    served from the cache alone, decision for decision."""
+    from access_control_srv_tpu.models import Attribute, Request, Target
+    from access_control_srv_tpu.srv.evaluator import HybridEvaluator
+    from synth import stress_engine
+
+    engine, _ = stress_engine(64, cacheable=True)
+    cache = DecisionCache(ttl_s=3600.0)
+    ev = HybridEvaluator(engine, decision_cache=cache)
+    # row i meets rule r{i}: role-i on Stress{i}, with its action
+    actions = [URNS[a] for a in ("read", "modify", "create", "delete")]
+    requests = [
+        Request(
+            target=Target(
+                subjects=[Attribute(id=URNS["role"], value=f"role-{i}"),
+                          Attribute(id=URNS["subjectID"], value=f"u{i}")],
+                resources=[Attribute(
+                    id=URNS["entity"],
+                    value=f"urn:restorecommerce:acs:model:stress{i}.Stress{i}",
+                )],
+                actions=[Attribute(id=URNS["actionID"], value=actions[i % 4])],
+            ),
+            context={"resources": [], "subject": {
+                "id": f"u{i}",
+                "role_associations": [{"role": f"role-{i}",
+                                       "attributes": []}],
+                "hierarchical_scopes": [],
+            }},
+        )
+        for i in range(16)
+    ]
+    warm = ev.is_allowed_batch(requests)
+    cacheable = [(r, w) for r, w in zip(requests, warm)
+                 if w.evaluation_cacheable is True]
+    assert cacheable
+
+    class NoDevice:
+        calls = []
+
+        def evaluate(self, batch):
+            self.calls.append(batch)
+            raise AssertionError("a cache hit reached the device")
+
+        evaluate_async = evaluate
+
+    assert ev._kernel is not None  # the cold rows rode the device
+    ev._kernel = NoDevice()
+    ev._native_encoder = None
+    hits = cache.stats()["hits"]
+    served = ev.is_allowed_batch([r for r, _ in cacheable])
+    assert NoDevice.calls == []
+    assert cache.stats()["hits"] - hits == len(cacheable)
+    assert [r.decision for r in served] == [w.decision for _, w in cacheable]
+
+
 class TestWorkerCachePath:
     def test_repeat_traffic_served_from_cache(self, worker):
         cold = worker.service.is_allowed(admin_request())

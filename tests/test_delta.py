@@ -373,6 +373,108 @@ class TestProgramReuse:
             assert np.asarray(arr).dtype == np.asarray(
                 full.arrays[name]).dtype, name
 
+    def test_replicas_replaying_one_journal_hold_identical_tables(self):
+        """Two stacks booted apart and fed the same CRUD sequence (update,
+        create, delete, upsert) converge to byte-identical compiled
+        tables and equal table fingerprints (the router's convergence
+        probe), and so run one program and serve the same decisions."""
+        stacks = [build_stack(cache=False) for _ in range(2)]
+        for _engine, _ev, store, _ in stacks:
+            rules = store.get_resource_service("rule")
+            rules.update([rule_doc("r3", 3, effect="DENY")])
+            rules.create([rule_doc("r12", 12)])
+            rules.delete(ids=["r7"])
+            rules.upsert([rule_doc("r12", 12, effect="DENY")])
+        (_, ev1, _, _), (_, ev2, _, _) = stacks
+        a1, a2 = ev1._compiled.arrays, ev2._compiled.arrays
+        assert sorted(a1) == sorted(a2)
+        for name in a1:
+            x, y = np.asarray(a1[name]), np.asarray(a2[name])
+            assert (x.dtype, x.shape) == (y.dtype, y.shape), name
+            assert x.tobytes() == y.tobytes(), name
+        assert ev1.table_fingerprint() is not None
+        assert ev1.table_fingerprint() == ev2.table_fingerprint()
+        requests = [make_request(k) for k in range(13)]
+        assert [r.decision for r in ev1.is_allowed_batch(requests)] == \
+            [r.decision for r in ev2.is_allowed_batch(requests)]
+
+
+def _through_admission(ev, requests):
+    """Rows admitted by an enabled controller, each with its deadline."""
+    from access_control_srv_tpu.srv.admission import AdmissionController
+
+    controller = AdmissionController(enabled=True)
+    deadline = time.monotonic() + 3600.0
+    for request in requests:
+        assert controller.admit("interactive", deadline) is None
+        request._deadline = deadline
+    try:
+        return ev.is_allowed_batch(requests)
+    finally:
+        controller.release("interactive", len(requests))
+        controller.observe_batch("interactive", 0.004, len(requests))
+
+
+def _through_tracing(ev, requests):
+    """Every row sampled, with the profiler annotations the worker uses."""
+    import jax
+
+    from access_control_srv_tpu.srv.tracing import Observability, StageTracer
+
+    tracer = StageTracer(sample_rate=1.0,
+                         annotate=jax.profiler.TraceAnnotation)
+    spans = []
+    for request in requests:
+        request._span = tracer.start_span()
+        request._sampling_done = True
+        spans.append(request._span)
+    ev.obs = Observability(tracer=tracer)
+    try:
+        served = ev.is_allowed_batch(requests)
+    finally:
+        ev.obs = None
+    for span in spans:
+        tracer.finish(span)
+    stages = {s["stage"] for t in tracer.traces() for s in t["stages"]}
+    assert {"encode", "device", "decode"} <= stages, stages
+    return served
+
+
+def _through_failpoints(ev, requests):
+    """Failpoints armed on both device-boundary sites, hit on every call."""
+    from access_control_srv_tpu.srv.faults import REGISTRY
+
+    with REGISTRY.arm([
+        {"site": "device.dispatch", "action": "delay", "delay_s": 0.0},
+        {"site": "device.materialize", "action": "delay", "delay_s": 0.0},
+    ], seed=11):
+        served = ev.is_allowed_batch(requests)
+        hits = dict(REGISTRY.stats()["hits_by_site"])
+    assert hits.get("device.dispatch", 0) >= 1, hits
+    assert hits.get("device.materialize", 0) >= 1, hits
+    return served
+
+
+class TestHostSideSubsystems:
+    @pytest.mark.parametrize("through", [
+        _through_admission, _through_tracing, _through_failpoints,
+    ], ids=["admission", "tracing", "failpoints"])
+    def test_rows_run_the_programs_plain_rows_compiled(self, through):
+        """Admission, stage tracing and failpoints act on the host around
+        the dispatch: rows passed through them run the executables that
+        plain rows of the same shape compiled (no new jit key, no new
+        XLA compilation) and get the same decisions."""
+        _, ev, _, _ = build_stack(cache=False)
+        plain = ev.is_allowed_batch([make_request(k) for k in range(12)])
+        keys = set(ev._shared_jits)
+        sizes = {k: f._cache_size() for k, f in ev._shared_jits.items()}
+        assert keys
+        served = through(ev, [make_request(k) for k in range(12)])
+        assert [r.decision for r in served] == [r.decision for r in plain]
+        assert set(ev._shared_jits) == keys
+        assert {k: f._cache_size()
+                for k, f in ev._shared_jits.items()} == sizes
+
 
 class TestScopedInvalidation:
     def test_disjoint_entries_survive_rule_mutation(self):

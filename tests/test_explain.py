@@ -230,14 +230,26 @@ def test_explain_prefilter_matches_oracle(fixture_name):
 
 
 def test_explain_off_keeps_three_outputs():
-    """explain=False kernels emit exactly the pre-explain output tuple
-    (the byte-identity of the lowered program is tpu_compat_audit.py's
-    explain-shadow-program-identity row)."""
+    """explain=False kernels emit exactly the pre-explain output tuple,
+    and in one shared jit registry the explain variant keys separately:
+    building and running it leaves the explain-off executables as they
+    were (no new compilation under an off key)."""
     engine = make_engine("role_scopes.yml")
     compiled = compile_policies(engine.policy_sets, engine.urns)
     batch = encode_requests(grid_requests(n=16), compiled)
     assert len(DecisionKernel(compiled).evaluate(batch)) == 3
     assert len(DecisionKernel(compiled, explain=True).evaluate(batch)) == 4
+
+    shared: dict = {}
+    off = DecisionKernel(compiled, dynamic_policies=True, shared_jits=shared)
+    assert len(off.evaluate(batch)) == 3
+    off_sizes = {k: f._cache_size() for k, f in shared.items()}
+    on = DecisionKernel(compiled, dynamic_policies=True, shared_jits=shared,
+                        explain=True)
+    assert len(on.evaluate(batch)) == 4
+    added = set(shared) - set(off_sizes)
+    assert added and all(k[-1] == "explain" for k in added)
+    assert {k: shared[k]._cache_size() for k in off_sizes} == off_sizes
 
 
 def test_explain_capacity_bound():
@@ -470,8 +482,13 @@ class TestShadow:
             )
             requests = _shadow_requests()
             responses = production.is_allowed_batch(requests)
+            jits = production._shared_jits
+            sizes = {k: f._cache_size() for k, f in jits.items()}
             shadow.submit(requests, responses)
             status = _drained_status(shadow)
+            # the candidate ran the production executables: zero new
+            # XLA compilations
+            assert {k: f._cache_size() for k, f in jits.items()} == sizes
             assert status["evaluated"] == len(requests)
             assert status["diffs"] == 0
             assert status["samples"] == []
@@ -574,8 +591,8 @@ class TestShadow:
         serving ``_deadline`` stamp (long expired by replay time) must
         not make the candidate path shed the row as deadline-expired:
         the caller was already answered, so the replay strips the stamp
-        on a copy without ever mutating the shared request.  Both found
-        live by the bench_all.py shadow-diff row."""
+        on a copy without ever mutating the shared request.  Both were
+        first seen under live shadow traffic."""
         from access_control_srv_tpu.srv.admission import (
             OVERLOAD_CODE,
             overload_response,

@@ -575,12 +575,12 @@ def test_wire_path_skips_the_retry_dispatch_when_no_row_fits():
 def _owner_bits_encoder():
     """A native encoder over an HR-scoped tree (hrv vocab non-empty) whose
     vocab the fuzz below overrides per case."""
-    import bench_all
+    import synth
     from access_control_srv_tpu.ops.compile import compile_policies
 
     if not native.available():
         pytest.skip(f"native encoder unavailable: {native.build_error()}")
-    engine, _ = bench_all._stress_engine(600, scoped=True)
+    engine, _ = synth.stress_engine(600, scoped=True)
     compiled = compile_policies(engine.policy_sets, engine.urns)
     return native.NativeBatchEncoder(compiled), compiled
 

@@ -131,13 +131,13 @@ class TestPrefilterStagingAliasing:
 
     @pytest.fixture(scope="class")
     def stress(self):
-        import bench_all
+        import synth
 
         from access_control_srv_tpu.ops.compile import compile_policies
         from access_control_srv_tpu.ops.encode import encode_requests
         from access_control_srv_tpu.ops.prefilter import PrefilteredKernel
 
-        engine, _ = bench_all._stress_engine(600)
+        engine, _ = synth.stress_engine(600)
         compiled = compile_policies(engine.policy_sets, engine.urns)
         kernel = PrefilteredKernel(compiled, staging=HostBufferPool())
         assert kernel.active  # >= MIN_RULES: the pooled sig path engages
@@ -165,11 +165,17 @@ class TestPrefilterStagingAliasing:
         b1, b2 = batch_for(1), batch_for(2)
         ref1 = kernel.evaluate(b1)
         ref2 = kernel.evaluate(b2)
+        runs = dict(kernel._runs)
+        sizes = {k: f._cache_size() for k, f in runs.items()}
         # dispatch BOTH before materializing EITHER: the pool must hand
         # each batch distinct buffers (the first is still leased)
         m1 = kernel.evaluate_async(b1)
         m2 = kernel.evaluate_async(b2)
         out1, out2 = m1(), m2()
+        # overlap is host orchestration: the depth-1 executables ran
+        # again, nothing was traced or compiled anew
+        assert kernel._runs == runs
+        assert {k: f._cache_size() for k, f in runs.items()} == sizes
         for ref, out in ((ref1, out1), (ref2, out2)):
             for r, o in zip(ref, out):
                 np.testing.assert_array_equal(np.asarray(r), np.asarray(o))
@@ -209,14 +215,14 @@ class TestPrefilterStagingAliasing:
 
 class TestNativeArenaAliasing:
     def _encoder(self):
-        import bench_all
+        import synth
 
         from access_control_srv_tpu import native
         from access_control_srv_tpu.ops.compile import compile_policies
 
         if not native.available():
             pytest.skip(f"native encoder unavailable: {native.build_error()}")
-        engine, _ = bench_all._stress_engine(600, scoped=True)
+        engine, _ = synth.stress_engine(600, scoped=True)
         compiled = compile_policies(engine.policy_sets, engine.urns)
         return native.NativeBatchEncoder(compiled)
 
@@ -257,8 +263,10 @@ class TestNativeArenaAliasing:
         b2.release_staging()
         # released: the next encode recycles (arena hit, no fresh numpy)
         misses_before = enc._pool.stats()["misses"]
+        arena_misses_before = enc.arena_stats()["misses"]
         b3 = enc.encode_wire(msgs, reuse=True)
         assert enc.arena_stats()["hits"] >= 1
+        assert enc.arena_stats()["misses"] == arena_misses_before
         assert enc._pool.stats()["misses"] == misses_before
         # ...and the recycled buffers carry the same content as b1 did
         ref = enc.encode_wire(msgs)

@@ -377,6 +377,18 @@ def test_tuple_churn_swaps_no_program():
     assert ev._compiled.version == version
     assert set(ev._shared_jits.keys()) == jits
 
+    # the same flip on the batch path, which dispatches to the device:
+    # the executables that served the closed tuple serve the open one
+    batch = _requests(["alice", "bob"], ["doc1", "doc2"])
+    closed = [r.decision for r in ev.is_allowed_batch(batch)]
+    sizes = {k: f._cache_size() for k, f in ev._shared_jits.items()}
+    assert sizes
+    store.create([(NS, "doc1", "viewer", "bob")])
+    opened = [r.decision for r in ev.is_allowed_batch(batch)]
+    assert closed[2] == "DENY" and opened[2] == "PERMIT"  # bob / doc1
+    assert {k: f._cache_size() for k, f in ev._shared_jits.items()} == sizes
+    store.delete([(NS, "doc1", "viewer", "bob")])
+
     # replica convergence covers tuple state: equal policy tables with
     # divergent tuple logs must fingerprint differently
     fp_before = ev.table_fingerprint()

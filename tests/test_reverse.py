@@ -143,8 +143,9 @@ def test_evaluator_wia_batch_and_hot_mutation(monkeypatch):
 
 def test_adaptive_wia_dispatch():
     """Small trees serve the reverse query from the scalar walk (the
-    device round-trip loses below REVERSE_MIN_RULES — bench_all.py wia
-    row measured ~6x); the threshold routes to the kernel above it."""
+    device round-trip is taken to lose below REVERSE_MIN_RULES; no
+    benchmark cell measures it); the threshold routes to the kernel
+    above it."""
     from access_control_srv_tpu.ops.reverse import REVERSE_MIN_RULES
     from access_control_srv_tpu.srv.evaluator import HybridEvaluator
     from access_control_srv_tpu.srv.telemetry import Telemetry

@@ -258,9 +258,9 @@ class TestTenantRegistry:
 
 
 class TestProgramPacking:
-    """The packing claim at unit scale (tpu_compat_audit.py runs it at
-    1k tenants): same-class tenants serve from ONE shared program and a
-    tenant's CRUD patches only its own tables with zero new compiles."""
+    """The packing claim at unit scale: same-class tenants serve from ONE
+    shared program and a tenant's CRUD patches only its own tables with
+    zero new compiles."""
 
     def test_same_class_tenants_share_compiled_programs(self):
         registry = TenantRegistry(URNS)  # hybrid: real shared-jit table

@@ -467,6 +467,41 @@ class TestContextQueryPrefetch:
         batch = self._run(cq_tree(), CountingAdapter())
         assert batch.eligible.all(), batch.ineligible_reasons
 
+    def test_resolved_and_prefetched_rows_run_the_plain_program(self):
+        """Token resolution and the context-query prefetch change only
+        host-computed kernel inputs: bare-token rows, half of them behind
+        a context query, run the executables that pre-resolved rows
+        compiled (no new jit key, no new XLA compilation), all on the
+        device and equal to the oracle."""
+        adapter = CountingAdapter()
+        engine = wired_engine(cq_tree())
+        engine.resource_adapter = adapter
+        telemetry = Telemetry()
+        ev = HybridEvaluator(engine, telemetry=telemetry)
+        plain = []
+        for i in range(16):
+            request = token_request(i, None)
+            request.context["subject"] = {**payload_for(i),
+                                          "hierarchical_scopes": []}
+            plain.append(request)
+        ev.is_allowed_batch(plain)
+        assert adapter.calls == 0
+        keys = set(ev._shared_jits)
+        sizes = {k: f._cache_size() for k, f in ev._shared_jits.items()}
+        assert keys
+        requests = [token_request(i, f"tok-{i % 8}",
+                                  entity=WIDGET if i % 2 else ORG)
+                    for i in range(16)]
+        oracle = [engine.is_allowed(copy.deepcopy(r)) for r in requests]
+        kernel_rows = telemetry.paths.get("kernel")
+        responses = ev.is_allowed_batch(requests)
+        assert_bit_identical(responses, oracle)
+        assert adapter.calls >= 1
+        assert telemetry.paths.get("kernel") - kernel_rows == len(requests)
+        assert set(ev._shared_jits) == keys
+        assert {k: f._cache_size()
+                for k, f in ev._shared_jits.items()} == sizes
+
     def test_merge_observable_rows_degrade(self):
         """A later role-gated candidate rule could see the merged context:
         those rows must take the oracle, and still match it."""
