@@ -136,6 +136,11 @@ def _load():
             ctypes.POINTER(ctypes.c_void_p),
             ctypes.POINTER(ctypes.c_int32),  # caps (13 ints) or None
         ]
+        lib.acs_enc_kept_hr.restype = ctypes.c_int32
+        lib.acs_enc_kept_hr.argtypes = [
+            ctypes.c_void_p, ctypes.c_int32,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ]
         lib.acs_own_max_runs.restype = ctypes.c_int32
         lib.acs_own_max_runs.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p,
@@ -444,9 +449,12 @@ class NativeBatchEncoder:
         ``caps`` overrides the per-request padding shapes (the floor
         defaults otherwise).  Rows that were ineligible ONLY because a
         cap overflowed come back flagged in ``batch.overcap`` (an
-        ``ops/encode.OVERCAP_*`` code) — the serving path re-encodes
-        exactly those rows at ``ops/encode.retry_caps`` of their codes so
-        deep-HR wire traffic stays native.
+        ``ops/encode.OVERCAP_*`` code) — the serving path retries exactly
+        those rows at ``ops/encode.retry_caps`` of their codes so deep-HR
+        wire traffic stays native.  Where every flagged row is over the
+        HR-scope pairs alone, ``batch.kept_hr`` carries their full
+        flattens from this same parse, and ``retry_from_first_pass``
+        builds the retry from them; otherwise the retry re-encodes.
 
         ``reuse=True`` draws every buffer (row arrays, masks, regex
         matrices, owner bits) from the staging arenas and attaches a
@@ -539,6 +547,8 @@ class NativeBatchEncoder:
                 if set_col:
                     rgx_set[:, e] = set_col
                     pfx_neq[:, e] = neq_col
+            # the next call clears the C++ side store: read it back now
+            kept_hr = self._kept_hr(overcap)
 
         # stage-B owner bitplanes, packed natively (bit-identical to the
         # Python packer ops/encode.pack_owner_bitplanes — structural for
@@ -572,5 +582,65 @@ class NativeBatchEncoder:
             eligible=eligible.view(np.bool_),
             requests=[],
             overcap=overcap,
+            kept_hr=kept_hr,
             staging=release,
+        )
+
+    def _kept_hr(self, overcap: np.ndarray) -> dict | None:
+        """The side store of the batch just encoded (caller holds
+        ``_call_lock``): its OVERCAP_NHR rows' HR-scope flattens at the
+        retry's NHR, or None where no row is OVERCAP_NHR or any row is
+        OVERCAP_OTHER (that retry re-encodes at the full ceiling)."""
+        rows = np.flatnonzero(overcap == _pyenc.OVERCAP_NHR)
+        if not rows.size or (overcap == _pyenc.OVERCAP_OTHER).any():
+            return None
+        nhr = _pyenc.retry_caps([_pyenc.OVERCAP_NHR])["NHR"]
+        n = int(rows.size)
+        hr = np.empty((n, nhr, 2), np.int32)
+        acl_hr = np.empty((n, nhr, 2), np.int32)
+        fit = np.empty((n,), np.uint8)
+        kept = self.lib.acs_enc_kept_hr(
+            self._handle, nhr, hr.ctypes.data, acl_hr.ctypes.data,
+            fit.ctypes.data,
+        )
+        if kept != n:
+            raise RuntimeError(f"native side store holds {kept} rows, not {n}")
+        return {"rows": rows, "r_hr": hr, "r_acl_hr": acl_hr,
+                "fit": fit.view(np.bool_)}
+
+    def retry_from_first_pass(self, batch: RequestBatch,
+                              relation_tables: dict | None = None,
+                              owner_bits_stage=None) -> RequestBatch:
+        """The retry batch of ``batch``'s OVERCAP_NHR rows (``batch.kept_hr``
+        is set), at ``ops/encode.retry_caps`` of their codes, without a
+        second parse: their floor row arrays, gathered, with the kept
+        HR-scope flattens; owner (and relation) bitplanes packed anew.
+        The regex matrices are the first pass's, whose entity columns
+        ``r_ent_e`` indexes.  A row over the retry's NHR stays
+        ineligible.  Freshly allocated (the gathers copy), so it outlives
+        the first batch's staging lease."""
+        kept = batch.kept_hr
+        rows = kept["rows"]
+        n = int(rows.size)
+        a = {name: batch.arrays[name][rows] for name in _ARRAY_ORDER}
+        a["r_hr"] = kept["r_hr"]
+        a["r_acl_hr"] = kept["r_acl_hr"]
+        arrays = dict(a)
+        with owner_bits_stage or contextlib.nullcontext():
+            arrays.update(self.owner_bits_native(a, n))
+        arrays.update(self.relation_bits_native(
+            a, n, tables=relation_tables
+        ))
+        eligible = kept["fit"].copy()
+        C = len(self.compiled.conditions)  # always 0 (ctor guard)
+        return RequestBatch(
+            B=n,
+            arrays=arrays,
+            rgx_set=batch.rgx_set,
+            pfx_neq=batch.pfx_neq,
+            cond_true=np.zeros((C, n), bool),
+            cond_abort=np.zeros((C, n), bool),
+            cond_code=np.full((C, n), 200, np.int32),
+            eligible=eligible,
+            requests=[],
         )

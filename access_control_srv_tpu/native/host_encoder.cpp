@@ -33,9 +33,11 @@ namespace {
 constexpr int32_t ABSENT = -1;
 // padding caps: runtime parameters of acs_enc_batch (13 int32s in the
 // order of ops/encode._CAPS_FLOOR); null means the floor defaults below.
-// The serving path encodes at the floor first and re-encodes over-cap
-// rows (flagged via the overcap output) at wider shapes, so deep-HR
-// traffic stays on the native fast path instead of falling to the oracle.
+// The serving path encodes at the floor first and retries over-cap rows
+// (flagged via the overcap output) at wider shapes, so deep-HR traffic
+// stays on the native fast path instead of falling to the oracle: rows
+// over the HR-scope pairs alone from this pass's own parse
+// (acs_enc_kept_hr), any other by a re-encode.
 struct Caps {
   int NR = 4, NI = 4, NP = 8, NSUB = 8, NACT = 4, NOP = 2;
   int NOWN = 4, NRA = 8, NHR = 32, NROLE = 4;
@@ -441,6 +443,13 @@ struct Encoder {
   std::vector<std::string> vocab_tails;  // tail strings of entity vocab
   // relevance cache keyed by "<tail idx>\x1f<prop value>"
   std::unordered_map<std::string, bool> relevance_ok;
+  // the full HR-scope flattens of the last acs_enc_batch call's rows over
+  // NHR alone (overcap code 2), in row order: the serving path's retry
+  // reads them back (acs_enc_kept_hr) instead of parsing those rows again
+  struct KeptHr {
+    std::vector<std::array<int32_t, 2>> hr, acl_hr;
+  };
+  std::vector<KeptHr> kept_hr;
 };
 
 struct OutArrays {
@@ -708,6 +717,7 @@ int32_t acs_enc_batch(void* h, const uint8_t* buf, const int64_t* offs,
 
   std::unordered_map<int32_t, int32_t> batch_entity_idx;
   int32_t n_batch_entities = 0;
+  enc.kept_hr.clear();
 
   for (int32_t b = 0; b < B; ++b) {
     std::string_view bytes((const char*)(buf + offs[b]),
@@ -1099,30 +1109,62 @@ int32_t acs_enc_batch(void* h, const uint8_t* buf, const int64_t* offs,
     if (!hs_missing)
       flatten_acl_hr(enc, hierarchical_scopes, false, std::string_view(),
                      acl_hr_enc, hr_roles);
-    // the last cap check of a row: every earlier one fit, so a row over
-    // NHR alone is told apart for the serving path's cheaper re-encode
-    bool over_hr = (int)hr_enc.size() > NHR || (int)acl_hr_enc.size() > NHR;
+    // the last cap checks of a row: every earlier one fit, so a row over
+    // NHR alone is told apart, and the serving path retries it from what
+    // this pass wrote plus its kept flattens instead of a re-encode
     bool over_rest = (int)ra3.size() > NRA || (int)ra2.size() > NRA ||
                      (int)hr_roles.size() > NHRR || overflow;
-    if (over_hr || over_rest) {
+    if (over_rest) {
       o.eligible[b] = 0;
-      o.overcap[b] = over_rest ? 1 : 2;
+      o.overcap[b] = 1;
       continue;
     }
     for (size_t j = 0; j < ra3.size(); ++j)
       for (int k = 0; k < 3; ++k) o.r_ra3[(b * NRA + j) * 3 + k] = ra3[j][k];
     for (size_t j = 0; j < ra2.size(); ++j)
       for (int k = 0; k < 2; ++k) o.r_ra2[(b * NRA + j) * 2 + k] = ra2[j][k];
+    for (size_t j = 0; j < hr_roles.size(); ++j)
+      o.r_hr_roles[b * NHRR + j] = hr_roles[j];
+    o.r_n_ra[b] = (int32_t)n_role_assocs;
+    if ((int)hr_enc.size() > NHR || (int)acl_hr_enc.size() > NHR) {
+      o.eligible[b] = 0;
+      o.overcap[b] = 2;
+      enc.kept_hr.push_back({std::move(hr_enc), std::move(acl_hr_enc)});
+      continue;
+    }
     for (size_t j = 0; j < hr_enc.size(); ++j)
       for (int k = 0; k < 2; ++k) o.r_hr[(b * NHR + j) * 2 + k] = hr_enc[j][k];
     for (size_t j = 0; j < acl_hr_enc.size(); ++j)
       for (int k = 0; k < 2; ++k)
         o.r_acl_hr[(b * NHR + j) * 2 + k] = acl_hr_enc[j][k];
-    for (size_t j = 0; j < hr_roles.size(); ++j)
-      o.r_hr_roles[b * NHRR + j] = hr_roles[j];
-    o.r_n_ra[b] = (int32_t)n_role_assocs;
   }
   return n_batch_entities;
+}
+
+// Write the kept HR-scope flattens of the last acs_enc_batch call's rows
+// over NHR alone, in row order, into hr_out / acl_hr_out [n, NHR, 2] at
+// the retry's NHR, ABSENT-padded (every element is written).  fit_out[n]
+// is 1 where both flattens fit it; a row over it is left all ABSENT, as
+// a re-encode at that NHR would leave it.  Returns n, the kept row count;
+// the caller holds the per-encoder call lock of that acs_enc_batch call.
+int32_t acs_enc_kept_hr(void* h, int32_t NHR, int32_t* hr_out,
+                        int32_t* acl_hr_out, uint8_t* fit_out) {
+  const auto& kept = ((Encoder*)h)->kept_hr;
+  for (size_t r = 0; r < kept.size(); ++r) {
+    const auto& hr = kept[r].hr;
+    const auto& acl_hr = kept[r].acl_hr;
+    fit_out[r] = (int)hr.size() <= NHR && (int)acl_hr.size() <= NHR;
+    int32_t* row_hr = hr_out + (int64_t)r * NHR * 2;
+    int32_t* row_acl_hr = acl_hr_out + (int64_t)r * NHR * 2;
+    std::fill(row_hr, row_hr + NHR * 2, ABSENT);
+    std::fill(row_acl_hr, row_acl_hr + NHR * 2, ABSENT);
+    if (!fit_out[r]) continue;
+    for (size_t j = 0; j < hr.size(); ++j)
+      for (int k = 0; k < 2; ++k) row_hr[j * 2 + k] = hr[j][k];
+    for (size_t j = 0; j < acl_hr.size(); ++j)
+      for (int k = 0; k < 2; ++k) row_acl_hr[j * 2 + k] = acl_hr[j][k];
+  }
+  return (int32_t)kept.size();
 }
 
 // --------------------------------------------------- owner-bit packing

@@ -52,8 +52,8 @@ from .interner import ABSENT
 # so deep-HR / wide-ACL traffic stays kernel-eligible instead of falling
 # to the scalar oracle, while common traffic keeps one compiled shape.
 # The native (C++) wire encoder takes the same caps at runtime: the wire
-# path encodes at the floor and re-encodes over-cap rows (batch.overcap)
-# at retry_caps, so deep rows stay on the native fast path too.
+# path encodes at the floor and retries over-cap rows (batch.overcap) at
+# retry_caps, so deep rows stay on the native fast path too.
 NR = 4      # entity runs
 NI = 4      # resource instances
 NP = 8      # property attributes
@@ -244,8 +244,13 @@ class RequestBatch:
     cond_msg: dict = field(default_factory=dict)
     # rows ineligible ONLY because a padding cap overflowed (native wire
     # encoder; uint8 OVERCAP_* codes, 0 where the row fit); the serving
-    # path re-encodes them at retry_caps
+    # path retries them at retry_caps
     overcap: Optional[np.ndarray] = None
+    # the native first pass's full HR-scope flattens of its OVERCAP_NHR rows
+    # at retry_caps' NHR, where no row is OVERCAP_OTHER: {"rows": row
+    # indices, "r_hr" / "r_acl_hr": [n, NHR, 2], "fit": [n] bool}; the
+    # serving path's retry assembles from them instead of a re-encode
+    kept_hr: Optional[dict] = None
     # pooled-staging lease (native zero-copy encode): a zero-arg callable
     # returning this batch's buffers to their arena.  MUST only run after
     # the consuming computation has materialized — on the CPU backend the

@@ -929,10 +929,11 @@ class HybridEvaluator:
                               time.perf_counter() - t_device)
             if batch.overcap is not None and batch.overcap.any():
                 # adaptive caps, native path: rows that overflowed the
-                # floor shapes re-encode natively at the caps their
-                # overflow asks for (one extra native call + one extra
-                # kernel dispatch when any of them then fits) instead of
-                # falling back to the oracle
+                # floor shapes are served natively at the caps their
+                # overflow asks for (one extra kernel dispatch when any of
+                # them then fits) instead of falling back to the oracle.
+                # Rows over the HR-scope pairs alone are assembled from
+                # the first pass's parse; any other overflow re-encodes.
                 from ..ops.encode import retry_caps
 
                 idx = [
@@ -941,12 +942,19 @@ class HybridEvaluator:
                 ]
                 self._count_path("native-wire-overcap", len(idx))
                 with stage(STAGE_WIRE_ENCODE_CEIL, span):
-                    retry = encoder.encode_wire(
-                        [messages[b] for b in idx],
-                        caps=retry_caps(batch.overcap[idx]),
-                        relation_tables=rel_tables,
-                        owner_bits_stage=stage(STAGE_WIRE_OWNER_BITS),
-                    )
+                    if batch.kept_hr is not None:
+                        retry = encoder.retry_from_first_pass(
+                            batch, relation_tables=rel_tables,
+                            owner_bits_stage=stage(STAGE_WIRE_OWNER_BITS),
+                        )
+                        self._count_path("native-wire-overcap-kept", len(idx))
+                    else:
+                        retry = encoder.encode_wire(
+                            [messages[b] for b in idx],
+                            caps=retry_caps(batch.overcap[idx]),
+                            relation_tables=rel_tables,
+                            owner_bits_stage=stage(STAGE_WIRE_OWNER_BITS),
+                        )
                 n_retried = int(retry.eligible.sum())
                 if n_retried:
                     with stage(STAGE_DEVICE_DISPATCH):

@@ -88,7 +88,7 @@ STAGE_CACHE = "cache.lookup"                 # decision-cache consult (hits)
 STAGE_ENCODE = "encode"                      # request -> kernel arrays
 STAGE_WIRE_ENCODE = "wire.encode"            # native C++ wire encode
 STAGE_WIRE_OWNER_BITS = "wire.owner_bits"    # owner-bitplane pack within it
-STAGE_WIRE_ENCODE_CEIL = "wire.encode.ceil"  # over-cap rows at ceiling caps
+STAGE_WIRE_ENCODE_CEIL = "wire.encode.ceil"  # over-cap retry at retry_caps
 STAGE_DEVICE = "device"                      # H2D + device eval + D2H
 STAGE_DEVICE_DISPATCH = "device.dispatch"    # host prep + H2D + launch
 STAGE_DEVICE_WAIT = "device.wait"            # blocked on result + D2H

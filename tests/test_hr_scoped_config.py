@@ -112,6 +112,10 @@ def test_cell_serves_correct_through_the_ceiling_path():
     assert paths.get("native-wire", 0) > 0
     assert paths.get("native-wire-ceil", 0) > 0
     assert paths.get("native-wire-overcap", 0) >= paths["native-wire-ceil"]
+    # every over-cap row is over the HR-scope pairs alone, so every retry
+    # comes from the first pass, and each of them fits the retry caps
+    assert paths.get("native-wire-overcap-kept") == paths["native-wire-overcap"]
+    assert paths["native-wire-ceil"] == paths["native-wire-overcap"]
     assert paths.get("oracle", 0) == 0
     assert seen["kernel"] == "PrefilteredKernel"
     assert seen["prefilter_active"] is False

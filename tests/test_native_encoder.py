@@ -520,12 +520,14 @@ def _spied_evaluator():
 
 @pytest.mark.parametrize("wide", [False, True], ids=["nhr_only", "wide"])
 def test_wire_path_retries_deep_hr_rows_before_the_ceiling(wide):
-    """Over-cap rows re-encode once, at the caps their overflow asks for:
+    """Over-cap rows are retried once, at the caps their overflow asks for:
     rows over the HR-scope pair cap alone at the floor with only NHR at the
-    ceiling; with a row over another cap among them, every row at the full
-    ceiling.  Both are served natively, with the oracle's decisions."""
+    ceiling, assembled from the first encode's own parse (one encode
+    call); with a row over another cap among them, every row re-encoded at
+    the full ceiling.  Both are served natively, with the oracle's
+    decisions."""
     from access_control_srv_tpu.ops.encode import (
-        _CAPS_CEIL, _CAPS_FLOOR, OVERCAP_NHR, OVERCAP_OTHER,
+        _CAPS_CEIL, OVERCAP_NHR, OVERCAP_OTHER,
     )
 
     engine, ev, telemetry, calls, dispatches = _spied_evaluator()
@@ -539,14 +541,17 @@ def test_wire_path_retries_deep_hr_rows_before_the_ceiling(wide):
     assert floor.overcap.tolist() == codes
     calls.clear()
     batch, decision, _, status = ev.is_allowed_batch_wire(messages)
-    caps = (dict(_CAPS_CEIL) if wide
-            else {**_CAPS_FLOOR, "NHR": _CAPS_CEIL["NHR"]})
     n_over = 2 if wide else 1
-    assert calls == [(len(reqs), None), (n_over, caps)]
+    if wide:
+        assert calls == [(len(reqs), None), (n_over, dict(_CAPS_CEIL))]
+    else:
+        assert calls == [(len(reqs), None)]
     assert dispatches == [len(reqs), n_over]
     assert bool(batch.eligible.all())
     assert telemetry.paths.get("native-wire-overcap") == n_over
     assert telemetry.paths.get("native-wire-ceil") == n_over
+    assert (telemetry.paths.get("native-wire-overcap-kept") or 0) == (
+        0 if wide else n_over)
     for b, req in enumerate(twins):
         expected = engine.is_allowed(req)
         assert decision[b] == DEC_CODE[expected.decision], b
@@ -555,18 +560,174 @@ def test_wire_path_retries_deep_hr_rows_before_the_ceiling(wide):
 
 def test_wire_path_skips_the_retry_dispatch_when_no_row_fits():
     """A row over even the ceiling (more HR-scope pairs than its 1024)
-    stays ineligible for the oracle, and the retry then dispatches
-    nothing to the device."""
+    stays ineligible for the oracle: its retry comes from the first
+    encode (one encode call), and then dispatches nothing to the
+    device."""
     _, ev, telemetry, calls, dispatches = _spied_evaluator()
     messages, _ = wire_roundtrip([_deep_hr_request(1100),
                                   _deep_hr_request(3, owner="org-1-n")])
     batch, _, _, status = ev.is_allowed_batch_wire(messages)
-    assert [n for n, _ in calls] == [2, 1]
+    assert [n for n, _ in calls] == [2]
     assert dispatches == [2]
     assert batch.eligible.tolist() == [False, True]
     assert status[1] == 200
     assert telemetry.paths.get("native-wire-overcap") == 1
+    assert telemetry.paths.get("native-wire-overcap-kept") == 1
     assert telemetry.paths.get("native-wire-ceil") == 0
+
+
+# arrays the first-pass retry must equal the re-encode's in, bit for bit
+_RETRY_ARRAYS = ("r_hr", "r_acl_hr", "r_hr_roles", "r_ra3", "r_ra2",
+                 "r_n_ra", "r_own_runs", "r_own_bits")
+
+
+def assert_retry_equals_reencode(first, reenc):
+    """The retry assembled from the first pass equals the re-encode of the
+    same rows at retry_caps: eligibility, every row array (the listed ones
+    above all), and, for each valid entity slot, the regex columns it
+    points at (``r_ent_e`` indexes each batch's own entity columns)."""
+    assert first.B == reenc.B
+    np.testing.assert_array_equal(first.eligible, reenc.eligible)
+    fit = reenc.eligible
+    assert set(first.arrays) == set(reenc.arrays) >= set(_RETRY_ARRAYS)
+    for name in first.arrays:
+        if name != "r_ent_e":
+            np.testing.assert_array_equal(
+                first.arrays[name][fit], reenc.arrays[name][fit],
+                err_msg=name)
+    valid = first.arrays["r_ent_valid"][fit]
+    np.testing.assert_array_equal(valid, reenc.arrays["r_ent_valid"][fit])
+    for mat in ("rgx_set", "pfx_neq"):
+        cols_first = getattr(first, mat)[:, first.arrays["r_ent_e"][fit][valid]]
+        cols_reenc = getattr(reenc, mat)[:, reenc.arrays["r_ent_e"][fit][valid]]
+        np.testing.assert_array_equal(cols_first, cols_reenc, err_msg=mat)
+
+
+@pytest.mark.parametrize("reuse", [False, True], ids=["fresh", "pooled"])
+def test_first_pass_retry_equals_the_reencode(reuse):
+    """Rows over the HR-scope pair cap alone keep their full flattens from
+    the first pass: the retry assembled from them equals the re-encode at
+    retry_caps, its kernel decisions are the oracle's, and a row over the
+    retry's 1024 pairs stays ineligible as the re-encode leaves it."""
+    from access_control_srv_tpu.ops.encode import (
+        OVERCAP_NHR, retry_caps,
+    )
+
+    engine = make_engine("role_scopes.yml")
+    compiled = compile_policies(engine.policy_sets, engine.urns)
+    enc = native.NativeBatchEncoder(compiled)
+    reqs = [_deep_hr_request(64, owner="org-40-n"),
+            _deep_hr_request(3, owner="org-1-n"),
+            _deep_hr_request(1100),
+            _deep_hr_request(33, role="admin", owner="org-20-n"),
+            _deep_hr_request(200, owner="nowhere")]
+    messages, twins = wire_roundtrip(reqs)
+    floor = enc.encode_wire(messages, reuse=reuse)
+    rows = [0, 2, 3, 4]
+    assert np.flatnonzero(floor.overcap).tolist() == rows
+    assert set(floor.overcap[rows].tolist()) == {OVERCAP_NHR}
+    assert floor.kept_hr["rows"].tolist() == rows
+    first = enc.retry_from_first_pass(floor)
+    reenc = enc.encode_wire([messages[b] for b in rows],
+                            caps=retry_caps(floor.overcap[rows]))
+    assert first.eligible.tolist() == [True, False, True, True]
+    assert_retry_equals_reencode(first, reenc)
+
+    decision, _, status = DecisionKernel(compiled).evaluate(first)
+    for j, b in enumerate(rows):
+        if first.eligible[j]:
+            expected = engine.is_allowed(twins[b])
+            assert decision[j] == DEC_CODE[expected.decision], b
+            assert status[j] == 200, b
+    floor.release_staging()
+
+
+def test_first_pass_keeps_nothing_beside_another_overflow():
+    """A batch with a row over a cap other than NHR keeps no flattens: its
+    retry re-encodes every over-cap row at the full ceiling; the next
+    batch's side store starts empty."""
+    from access_control_srv_tpu.ops.encode import OVERCAP_NHR, OVERCAP_OTHER
+
+    engine = make_engine("role_scopes.yml")
+    compiled = compile_policies(engine.policy_sets, engine.urns)
+    enc = native.NativeBatchEncoder(compiled)
+    messages, _ = wire_roundtrip([_deep_hr_request(64, owner="org-40-n"),
+                                  _wide_deep_hr_request()])
+    batch = enc.encode_wire(messages)
+    assert batch.overcap.tolist() == [OVERCAP_NHR, OVERCAP_OTHER]
+    assert batch.kept_hr is None
+    shallow, _ = wire_roundtrip([_deep_hr_request(3, owner="org-1-n")])
+    assert enc.encode_wire(shallow).kept_hr is None
+
+
+POOL_RETRY = """
+import json, sys
+import numpy as np
+import yaml
+sys.path.insert(0, "benchmark")
+sys.path.insert(0, ".")
+import harness, traffic
+from access_control_srv_tpu import native
+from access_control_srv_tpu.core import AccessController, populate
+from access_control_srv_tpu.ops import DecisionKernel
+from access_control_srv_tpu.ops import encode as pyenc
+from access_control_srv_tpu.ops.compile import compile_policies
+from access_control_srv_tpu.srv.gen import access_control_pb2 as pb
+from access_control_srv_tpu.srv.service import coerce_request
+from access_control_srv_tpu.srv.transport_grpc import request_from_pb
+from tests.test_kernel_differential import DEC_CODE
+from tests.test_native_encoder import assert_retry_equals_reencode
+
+cell = harness.load_cell(%(cell)r)
+cell = harness.load_cell(%(cell)r, scale={"mix": cell.mix["small"]})
+wl = harness.build_workload(cell, %(seed)d)
+with open(%(tmp)r, "w") as f:
+    yaml.safe_dump({"policy_sets": wl.tree}, f)
+engine = AccessController()
+populate(engine, %(tmp)r)
+compiled = compile_policies(engine.policy_sets, engine.urns)
+enc = native.NativeBatchEncoder(compiled)
+kernel = DecisionKernel(compiled)
+report = {"overcap": 0, "kept": 0, "retried": 0}
+for rows in wl.pool:
+    messages = []
+    for row in rows:
+        msg = pb.Request()
+        traffic.fill_pb(msg, row)
+        messages.append(msg.SerializeToString())
+    floor = enc.encode_wire(messages)
+    over = np.flatnonzero(floor.overcap)
+    report["overcap"] += len(over)
+    if floor.kept_hr is None:
+        continue
+    report["kept"] += len(floor.kept_hr["rows"])
+    first = enc.retry_from_first_pass(floor)
+    reenc = enc.encode_wire([messages[b] for b in over],
+                            caps=pyenc.retry_caps(floor.overcap[over]))
+    assert_retry_equals_reencode(first, reenc)
+    decision, _, status = kernel.evaluate(first)
+    for j, b in enumerate(over):
+        assert first.eligible[j]
+        want = engine.is_allowed(coerce_request(
+            request_from_pb(pb.Request.FromString(messages[b]))))
+        assert decision[j] == DEC_CODE[want.decision], int(b)
+        assert status[j] == 200, int(b)
+        report["retried"] += 1
+print(json.dumps(report))
+"""
+
+
+def test_first_pass_retry_equals_the_reencode_on_the_hr_scoped_pool(tmp_path):
+    """The benchmark's HR-scoped pool at its test size: every over-cap row
+    is over the HR-scope pairs alone, and the retry assembled from the
+    first pass equals the re-encode and gives the oracle's decisions."""
+    from .test_hr_scoped_config import CELL, SEED, _child
+
+    got = _child(POOL_RETRY % {"cell": CELL, "seed": SEED,
+                               "tmp": str(tmp_path / "tree.yml")})
+    assert got["overcap"] > 0
+    assert got["kept"] == got["overcap"]
+    assert got["retried"] == got["overcap"]
 
 
 # ------------------------------------------------- owner-bit packer parity
