@@ -88,6 +88,36 @@ def half_pow2_bucket(n: int, floor: int = 8) -> int:
     return p - p // 4 if n <= p - p // 4 and p - p // 4 >= floor else p
 
 
+def batch_has_acl(batch) -> bool:
+    """Whether ``batch`` carries ACL pairs: the dense kernel then runs
+    ``acs_dense_acl`` (the full verifyACL pass), else ``acs_dense``."""
+    return bool((np.asarray(batch.arrays["r_acl_ent"]) >= 0).any())
+
+
+# each cap of a request batch: (array, axis) holding it; OWN and REL are
+# the owner and relation bitplane words
+_CAP_AXES = {
+    "NR": ("r_ent_vals", 1), "NI": ("r_inst_id", 1), "NP": ("r_prop_vals", 1),
+    "NSUB": ("r_sub_ids", 1), "NACT": ("r_act_ids", 1), "NOP": ("r_op_vals", 1),
+    "NOWN": ("r_inst_owner_ent", 2), "NRA": ("r_ra3", 1), "NHR": ("r_hr", 1),
+    "NROLE": ("r_roles", 1), "NACLE": ("r_acl_ent", 1),
+    "NACLI": ("r_acl_inst", 2), "NHRR": ("r_hr_roles", 1),
+    "OWN": ("r_own_bits", 1), "REL": ("r_rel_bits", 1),
+}
+
+
+def dispatch_shape_key(prefix: str, batch, targets: int) -> str:
+    """``<prefix>@b<rows>.t<targets>.NR<n>...``: the shape a dispatch of
+    ``batch`` runs at (rows padded to their bucket, the tree's target
+    rows, every cap), as a path-counter key a byte count of the program
+    can be made from."""
+    rows = np.shape(batch.arrays["r_acl_short"])[0]
+    shape = [f"b{pow2_bucket(rows)}", f"t{targets}"]
+    for cap, (name, axis) in _CAP_AXES.items():
+        shape.append(f"{cap}{np.shape(batch.arrays[name])[axis]}")
+    return f"{prefix}@{'.'.join(shape)}"
+
+
 def pack_rule_key(pos, effect, cacheable):
     """Combine-reduction key: rule position in the high bits, (effect,
     cacheable) payload in the low 3, so position min/max reductions carry
@@ -1319,11 +1349,7 @@ class DecisionKernel:
         # dispatch on ACL content: only batches actually carrying ACL
         # pairs pay for the tensorized verifyACL create-scan (the no-pair
         # variant is exact for everything else, incl. short==1/2 rows)
-        run = (
-            self._run_acl
-            if bool((np.asarray(batch.arrays["r_acl_ent"]) >= 0).any())
-            else self._run_noacl
-        )
+        run = self._run_acl if batch_has_acl(batch) else self._run_noacl
         out = run(
             {k: jnp.asarray(pad_lead(v)) for k, v in batch.arrays.items()},
             jnp.asarray(pad_cols(batch.rgx_set, e_bucket)),

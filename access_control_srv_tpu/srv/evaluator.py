@@ -39,7 +39,7 @@ from ..models.model import Decision, OperationStatus, Response
 from ..ops import delta as delta_mod
 from ..ops.compile import DECISION_NAMES, compile_policies
 from ..ops.encode import encode_requests
-from ..ops.kernel import DecisionKernel
+from ..ops.kernel import DecisionKernel, batch_has_acl, dispatch_shape_key
 from .decision_cache import request_features
 from .tracing import (
     STAGE_CACHE,
@@ -56,6 +56,15 @@ from .tracing import (
     untimed,
 )
 from .watchdog import DeviceTimeoutError
+
+
+def _acl_rows(batch) -> np.ndarray:
+    """[B] rows that carry ACL pairs to the kernel's verifyACL pass: no
+    early all-clear or malformed ACL (``r_acl_short`` 0) and at least one
+    ACL entity."""
+    arrays = batch.arrays
+    return ((np.asarray(arrays["r_acl_short"]) == 0)
+            & (np.asarray(arrays["r_acl_ent"]) >= 0).any(axis=1))
 
 
 class HybridEvaluator:
@@ -917,13 +926,12 @@ class HybridEvaluator:
         # host boundary; they go to the histograms and the profiler's
         # timeline, while the span keeps ``device`` whole
         with stage(STAGE_DEVICE_DISPATCH):
-            materialize = self._guard_materialize(
-                kernel.evaluate_async(batch)
-            )
+            materialize = self._dispatch(kernel, batch)
 
         def finalize():
             with stage(STAGE_DEVICE_WAIT):
                 decision, cacheable, status = materialize()[:3]
+            acl = _acl_rows(batch)
             if tracer is not None:
                 tracer.record(span, STAGE_DEVICE,
                               time.perf_counter() - t_device)
@@ -958,27 +966,29 @@ class HybridEvaluator:
                 n_retried = int(retry.eligible.sum())
                 if n_retried:
                     with stage(STAGE_DEVICE_DISPATCH):
-                        materialize_retry = self._guard_materialize(
-                            kernel.evaluate_async(retry)
-                        )
+                        materialize_retry = self._dispatch(kernel, retry)
                     with stage(STAGE_DEVICE_WAIT):
                         d2, c2, s2 = materialize_retry()[:3]
                     # kernel outputs are read-only views on device buffers
                     decision = np.array(decision)
                     cacheable = np.array(cacheable)
                     status = np.array(status)
+                    acl2 = _acl_rows(retry)
                     for j, b in enumerate(idx):
                         if retry.eligible[j]:
                             batch.eligible[b] = True
                             decision[b] = d2[j]
                             cacheable[b] = c2[j]
                             status[b] = s2[j]
+                            acl[b] = acl2[j]
                 self._count_path("native-wire-ceil", n_retried)
             n_served = sum(
                 1 for b in range(len(messages))
                 if batch.eligible[b] and status[b] == 200
             )
             self._count_path("native-wire", n_served)
+            self._count_path("native-wire-acl", int(
+                (acl & batch.eligible & (status == 200)).sum()))
             return batch, decision, cacheable, status
 
         return finalize
@@ -1272,6 +1282,19 @@ class HybridEvaluator:
         self._count_path("kernel-wia", len(requests) - n_oracle)
         return out
 
+    def _dispatch(self, kernel, batch):
+        """``kernel.evaluate_async(batch)`` under the watchdog's guard.  A
+        batch with ACL pairs counts once under ``acl-program`` (the dense
+        kernel runs it on ``acs_dense_acl``) and once under its dispatched
+        shape (``ops/kernel.dispatch_shape_key``)."""
+        materialize = self._guard_materialize(kernel.evaluate_async(batch))
+        if self.telemetry is not None and batch_has_acl(batch):
+            targets = np.shape(kernel.compiled.arrays["t_role"])[0]
+            self._count_path("acl-program", 1)
+            self._count_path(
+                dispatch_shape_key("acl-program", batch, targets), 1)
+        return materialize
+
     def _count_path(self, path: str, rows: int) -> None:
         if self.telemetry is not None and rows:
             self.telemetry.paths.inc(path, rows)
@@ -1477,9 +1500,7 @@ class HybridEvaluator:
             )
         t_device = time.perf_counter()
         with stage(STAGE_DEVICE_DISPATCH):
-            materialize = self._guard_materialize(
-                kernel.evaluate_async(batch)
-            )
+            materialize = self._dispatch(kernel, batch)
 
         def finalize():
             try:
